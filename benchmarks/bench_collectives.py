@@ -89,8 +89,9 @@ print("RESULT " + json.dumps(res))
 
 
 def run():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
+    # the child runs on virtual CPU devices: on a TPU host the parent process
+    # already holds the chip, and a child that reached for it would fail
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                          capture_output=True, text=True, timeout=600)
     line = [ln for ln in out.stdout.splitlines() if ln.startswith("RESULT ")]

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from benchmarks.common import emit
 from repro.configs import get_arch
 from repro.data.pipeline import SyntheticLMPipeline
-from repro.launch.dryrun import _parse_policy
+from repro.core.pcsr import TransPolicy
 from repro.launch.steps import make_train_step
 from repro.models.registry import build_model
 from repro.obs import prof
@@ -48,7 +48,7 @@ MAX_OVERHEAD = 0.05
 def run(smoke: bool = False) -> None:
     rounds = 2 if smoke else 4
     cfg = get_arch("xlstm-125m").reduced()
-    policy = _parse_policy("p16-train")
+    policy = TransPolicy.from_spec("p16-train")
     model = build_model(cfg)
     opt_cfg = AdamWConfig(lr=1e-3, moment_fmt=policy.optimizer)
     params = model.init(jax.random.key(0))

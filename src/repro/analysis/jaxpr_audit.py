@@ -116,7 +116,7 @@ def _dtype(v):
 
 
 def _sub_jaxprs(eqn):
-    """Every sub-jaxpr an equation closes over (pjit/scan/while/cond/...)."""
+    """Every sub-jaxpr an equation closes over (jit/scan/while/cond/...)."""
     for val in eqn.params.values():
         vals = val if isinstance(val, (tuple, list)) else (val,)
         for v in vals:
@@ -328,7 +328,7 @@ class _Audit:
     _DECODE_EPILOGUE = frozenset({
         "convert_element_type", "select_n", "mul", "neg", "reshape",
         "broadcast_in_dim", "transpose", "squeeze", "copy",
-        "pjit",  # jnp.where wraps its select in a pjit — pass through it
+        "jit",  # jnp.where wraps its select in a jit — pass through it
     })
 
     @classmethod

@@ -293,7 +293,5 @@ def collect_stats(forward_fn, batches) -> Observer:
             out = forward_fn(batch)
             jax.block_until_ready(out)
     # debug.callback effects are asynchronous; drain them before reading stats
-    barrier = getattr(jax, "effects_barrier", None)
-    if barrier is not None:
-        barrier()
+    jax.effects_barrier()
     return obs

@@ -76,7 +76,7 @@ def posit_decode(codes: jax.Array, nbits: int, es: EsLike) -> jax.Array:
     # Locate the regime terminator: flip the run to zeros, find the highest set
     # bit. w < 2^15, so the f32-exponent floor-log2 is exact (Mosaic-safe).
     w = jnp.where(r0 == 1, (~absc) & _u32((1 << (n - 1)) - 1), absc)
-    p = _floor_log2_small(jnp.maximum(w, 1).astype(jnp.int32))
+    p = _floor_log2_small(jnp.maximum(w.astype(jnp.int32), 1))
     m = jnp.where(w == 0, n - 1, (n - 2) - p)  # regime run length
     k = jnp.where(r0 == 1, m - 1, -m)  # int32
 
@@ -135,7 +135,7 @@ def _decode_fields(codes: jax.Array, nbits: int, esl: jax.Array):
     absc = jnp.where(neg, (_u32(1 << n) - c) & _u32((1 << n) - 1), c)
     r0 = (absc >> _u32(n - 2)) & _u32(1)
     w = jnp.where(r0 == 1, (~absc) & _u32((1 << (n - 1)) - 1), absc)
-    p = _floor_log2_small(jnp.maximum(w, 1).astype(jnp.int32))
+    p = _floor_log2_small(jnp.maximum(w.astype(jnp.int32), 1))
     m = jnp.where(w == 0, n - 1, (n - 2) - p)  # regime run length
     k = jnp.where(r0 == 1, m - 1, -m)
     y = absc << _u32(33 - n)
@@ -193,7 +193,7 @@ def _encode_fields(
     body = (reg << t) | tail
     inc = (g == 1) & (st | ((body & 1) == 1))
     body = body + inc.astype(_U32)
-    body = jnp.minimum(body, _u32((1 << (n - 1)) - 1))
+    body = jnp.minimum(body.astype(jnp.int32), (1 << (n - 1)) - 1).astype(_U32)
     body = jnp.where(sat_hi, _u32((1 << (n - 1)) - 1), jnp.where(sat_lo, _u32(1), body))
 
     code = jnp.where(neg, _u32(1 << n) - body, body) & _u32((1 << n) - 1)

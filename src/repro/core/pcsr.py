@@ -232,6 +232,28 @@ class TransPolicy:
             kw[role] = fmt
         return cls(compute_dtype=compute_dtype, **kw)
 
+    @classmethod
+    def from_spec(cls, spec: str) -> "TransPolicy":
+        """Parse a command-line policy spec: ``none`` | ``p16-train`` |
+        ``p8-serve`` | ``weights=p8_0,kv=p8_0,compute=bf16,...``."""
+        if spec in ("none", ""):
+            return cls()
+        if spec == "p16-train":
+            return cls.from_names(weights="p16_1", gradients="p16_1",
+                                  optimizer="p16_1", checkpoint="p16_1")
+        if spec == "p8-serve":
+            return cls.from_names(weights="p8_0", kv_cache="p8_0",
+                                  compute_dtype="bf16")
+        kw = {}
+        cd = "f32"
+        for part in spec.split(","):
+            k, v = part.split("=")
+            if k == "compute":
+                cd = v
+            else:
+                kw[{"kv": "kv_cache"}.get(k, k)] = v
+        return cls.from_names(compute_dtype=cd, **kw)
+
     def to_json(self) -> dict:
         """JSON-ready dict: format roles by name, knobs verbatim.
 

@@ -37,17 +37,6 @@ from repro.core.quire import (
 from repro.core.types import PositFmt
 
 
-def _axis_size(axis: str) -> int:
-    """Static size of a named mesh axis (lax.axis_size on current jax; the
-    axis-env frame on older releases where it does not exist yet)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    import jax.core as jcore
-
-    frame = jcore.axis_frame(axis)  # returns the size directly on some versions
-    return frame if isinstance(frame, int) else frame.size
-
-
 def _pow2_scale(x: jax.Array, axis: Optional[str]):
     """Exact power-of-2 normalizer centering |x| at posit's accuracy peak.
 
@@ -68,7 +57,7 @@ def _pow2_scale(x: jax.Array, axis: Optional[str]):
 def compressed_allreduce(x: jax.Array, fmt: PositFmt, axis: str,
                          es=None) -> jax.Array:
     """Two-hop posit-compressed all-reduce over `axis` (inside shard_map)."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     shape = x.shape
     xf = x.astype(jnp.float32).reshape(-1)
     M = xf.shape[0]

@@ -1,19 +1,10 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-"""Shared Pallas-TPU compatibility shims + tiling helpers for the kernel
-packages."""
+"""Shared tiling helpers for the Pallas-TPU kernel packages."""
 import jax.numpy as _jnp
-from jax.experimental.pallas import tpu as _pltpu
 
 LANE = 128
-
-
-def tpu_compiler_params(**kw):
-    """pltpu.CompilerParams across jax versions (renamed from
-    TPUCompilerParams in newer releases)."""
-    cls = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
-    return cls(**kw)
 
 
 def sublane(dtype) -> int:

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 from repro.core.codec import posit_decode, posit_encode
 
 _LANES = 128
@@ -60,7 +58,7 @@ def decode_kernel(
             out_specs=pl.BlockSpec((block_rows, _LANES), lambda i, s: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((rows_p, _LANES), jnp.dtype(out_dtype_name)),
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(jnp.asarray([es], jnp.int32).reshape(1), tiled)
     return out.reshape(-1)[:size].reshape(shape)
@@ -83,7 +81,7 @@ def encode_kernel(
             out_specs=pl.BlockSpec((block_rows, _LANES), lambda i, s: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((rows_p, _LANES), out_dtype),
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(jnp.asarray([es], jnp.int32).reshape(1), tiled)
     return out.reshape(-1)[:size].reshape(shape)

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import LANE, pad_to, round_block, sublane, tpu_compiler_params
+from repro.kernels import LANE, pad_to, round_block, sublane
 
 from repro.core.codec import posit_decode, posit_encode
 from repro.core.dot import ACTIVATIONS, _apply_activation
@@ -251,7 +251,7 @@ def posit_gemm(
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -38,7 +38,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import LANE, pad_to, round_block, sublane, tpu_compiler_params
+from repro.kernels import LANE, pad_to, round_block, sublane
 
 from repro.core.codec import _decode_fields, _es_u32, posit_encode
 from repro.core.dot import ACTIVATIONS, _apply_activation
@@ -193,7 +193,7 @@ def posit_quire_gemm(
             scratch_shapes=[pltpu.VMEM((bm, bn, qfmt.limbs_axis), jnp.int32)],
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -13,8 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 from repro.core.codec import posit_decode, posit_encode
 
 
@@ -47,7 +45,7 @@ def posit_softmax_kernel(
             out_specs=pl.BlockSpec((br, Cp), lambda i, s: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((Rp, Cp), codes.dtype),
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(jnp.asarray([es], jnp.int32), padded)
     return out[:R, :C]

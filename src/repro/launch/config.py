@@ -25,9 +25,28 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
+import pathlib
 from typing import Optional
 
-__all__ = ["ServeConfig", "add_cli_args", "config_from_args"]
+__all__ = ["ServeConfig", "add_cli_args", "config_from_args",
+           "use_compile_cache"]
+
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one fixed path in the checkout (the path is part of the cache key,
+#: so a directory that moved between runs would never hit).
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for this process: the
+    directory ``JAX_COMPILATION_CACHE_DIR`` names (JAX reads it itself), or
+    else :data:`COMPILE_CACHE_DIR`.  Call before the first compile."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
 
 _KIND = "repro/serve-config"
 _VERSION = 1
@@ -48,7 +67,7 @@ class ServeConfig:
     batch: int = _f(4, "static batch size (and default --max-slots)")
     prompt_len: int = _f(32, "prompt length in tokens")
     gen: int = _f(16, "tokens to generate per request")
-    policy: str = _f("none", "base TransPolicy spec (launch/dryrun grammar)")
+    policy: str = _f("none", "base TransPolicy spec (TransPolicy.from_spec grammar)")
     seed: int = _f(0, "PRNG seed (params, workload, sampler)")
     # ----- engine -----
     continuous: bool = _f(False, "continuous batching via launch/engine.py")
@@ -192,10 +211,10 @@ class ServeConfig:
         fields — the one resolution path serve.py / hillclimb / benches use.
         ``base`` overrides the ``policy`` spec with an already-built
         TransPolicy (hillclimb's variant table hands these in directly)."""
+        from repro.core.pcsr import TransPolicy
         from repro.core.policy import get_precision_policy
-        from repro.launch.train import _parse_policy
         policy = dataclasses.replace(
-            base if base is not None else _parse_policy(self.policy),
+            base if base is not None else TransPolicy.from_spec(self.policy),
             codec_impl=self.codec_impl, epilogue=self.epilogue,
             attn_impl=self.attn_impl)
         drift_meta = None
@@ -205,6 +224,26 @@ class ServeConfig:
                 with open(self.precision_policy[1:]) as f:
                     drift_meta = json.load(f)
         return policy, drift_meta
+
+    def load_model(self, policy):
+        """(model, params) for ``arch`` with weights made from ``seed``.
+
+        Under ``quantize_weights`` the weights are posit-coded under
+        ``policy`` as they are made, one leaf at a time
+        (``models.layers.init_quantized_params``), so a model whose f32
+        weights exceed device memory still loads.  A ``calibrate`` run gets
+        float weights: the calibration must see them before it picks the
+        policy that codes them."""
+        import jax
+
+        from repro.models.layers import init_quantized_params
+        from repro.models.registry import build_model
+
+        model = build_model(self.arch_cfg())
+        key = jax.random.key(self.seed)
+        if self.quantize_weights and not self.calibrate:
+            return model, init_quantized_params(model.init, key, policy)
+        return model, model.init(key)
 
     def build_engine(self, model, params, policy, **sinks):
         """Construct the serving engine this config describes.

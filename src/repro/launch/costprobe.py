@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """Scan-aware cost probes for the roofline (EXPERIMENTS.md §Roofline).
 
 XLA's HLO cost analysis counts while-loop bodies once, so scanned stacks
@@ -22,13 +19,15 @@ are microbatch-invariant; collectives differ <~1/micro in the accumulate sums).
 import argparse
 import dataclasses
 import json
+import os
 import sys
+
+import jax
 
 from repro.configs import get_arch, get_shape
 from repro.core.pcsr import TransPolicy
 from repro.launch.mesh import make_production_mesh
-from repro.launch.dryrun import (cost_analysis_dict, lower_cell,
-                                 parse_collectives, _parse_policy)
+from repro.launch.dryrun import lower_cell, parse_collectives
 from repro.models.unroll import unroll_mode
 
 
@@ -59,7 +58,7 @@ def _measure(cfg, shape, mesh, policy, grad_sync):
         lowered = lower_cell(cfg, shape, mesh, policy=policy,
                              grad_sync=grad_sync, force_micro=1)
     compiled = lowered.compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = parse_collectives(compiled.as_text())
     return {
         "flops": cost.get("flops", 0.0),
@@ -109,7 +108,7 @@ def main(argv=None):
     for arch, shape in todo:
         try:
             res = probe_cell(arch, shape, multi_pod=args.multi_pod,
-                             policy=_parse_policy(args.policy),
+                             policy=TransPolicy.from_spec(args.policy),
                              grad_sync=args.grad_sync)
         except Exception as e:
             res = {"arch": arch, "shape": shape,
@@ -129,4 +128,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the production meshes need 512 devices: virtual ones on the CPU
+    jax.config.update("jax_num_cpu_devices", 512)
     main()

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: device count locks on first backend init.
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and extract the roofline inputs from the compiled artifact.
 
@@ -13,9 +9,13 @@ Per cell this prints (and JSON-dumps):
   * compiled.cost_analysis()     — HLO FLOPs / bytes for §Roofline
   * the collective schedule      — op counts + payload bytes by dtype,
                                    parsed from the post-SPMD optimized HLO
+
+Run as a program, it gives the CPU backend 512 virtual devices
+(``jax_num_cpu_devices``); importing it changes nothing.
 """
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -111,16 +111,6 @@ def parse_collectives(hlo_text: str) -> dict:
                 "by_dtype": dict(v["by_dtype"])} for k, v in stats.items()}
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: newer jax returns a
-    flat dict, older (and some backends) a one-element list of dicts — the
-    ``run_cell`` AttributeError of CHANGES.md (PR 2).  Normalize to a dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def lower_cell(cfg: ModelCfg, shape: ShapeCfg, mesh, *,
                policy: TransPolicy, grad_sync: str = "gspmd",
                force_micro: int | None = None):
@@ -209,7 +199,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     print(mem, file=sys.stderr)
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     print({k: v for k, v in cost.items()
            if k in ("flops", "bytes accessed") and isinstance(v, (int, float))},
           file=sys.stderr)
@@ -254,7 +244,7 @@ def main(argv=None):
                     help="skip collective parsing (faster)")
     args = ap.parse_args(argv)
 
-    policy = _parse_policy(args.policy)
+    policy = TransPolicy.from_spec(args.policy)
     cells = []
     if args.all:
         for a in list_archs():
@@ -288,25 +278,7 @@ def main(argv=None):
     sys.exit(0 if ok else 1)
 
 
-def _parse_policy(s: str) -> TransPolicy:
-    if s in ("none", ""):
-        return TransPolicy()
-    if s == "p16-train":
-        return TransPolicy.from_names(weights="p16_1", gradients="p16_1",
-                                      optimizer="p16_1", checkpoint="p16_1")
-    if s == "p8-serve":
-        return TransPolicy.from_names(weights="p8_0", kv_cache="p8_0",
-                                      compute_dtype="bf16")
-    kw = {}
-    cd = "f32"
-    for part in s.split(","):
-        k, v = part.split("=")
-        if k == "compute":
-            cd = v
-        else:
-            kw[{"kv": "kv_cache"}.get(k, k)] = v
-    return TransPolicy.from_names(compute_dtype=cd, **kw)
-
-
 if __name__ == "__main__":
+    # the production meshes need 512 devices: virtual ones on the CPU
+    jax.config.update("jax_num_cpu_devices", 512)
     main()

@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """§Perf hillclimb driver: measure (probe) a cell under a named variant.
 
     PYTHONPATH=src python -m repro.launch.hillclimb --cell olmoe_train --variant bf16
@@ -12,13 +9,16 @@ every hypothesis->change->measure cycle in EXPERIMENTS.md §Perf is one command.
 import argparse
 import dataclasses
 import json
+import os
+
+import jax
 
 from repro.configs import get_arch, get_shape
 from repro.core.pcsr import TransPolicy
 from repro.core.policy import PRECISION_PRESETS
 from repro.launch import costprobe
 from repro.launch.config import ServeConfig
-from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS, model_flops
+from repro.launch.roofline import TARGET, model_flops
 
 CELLS = {
     "olmoe_train": ("olmoe-1b-7b", "train_4k"),
@@ -126,9 +126,9 @@ def run_variant(cell: str, variant: str,
 
     shape = get_shape(shape_name)
     chips = res["n_chips"]
-    t_c = res["flops_per_device"] / PEAK_FLOPS
-    t_m = res["bytes_per_device"] / HBM_BW
-    t_x = res["coll_per_device"] / ICI_BW
+    t_c = res["flops_per_device"] / TARGET["flops"]
+    t_m = res["bytes_per_device"] / TARGET["hbm_bw"]
+    t_x = res["coll_per_device"] / TARGET["ici_bw"]
     mf = model_flops(cfg, shape)
     res.update({
         "variant": variant, "cell": cell,
@@ -137,7 +137,7 @@ def run_variant(cell: str, variant: str,
                         key=lambda k: {"compute": t_c, "memory": t_m,
                                        "collective": t_x}[k]),
         "model_flops": mf,
-        "roofline_fraction": (mf / chips / PEAK_FLOPS) / max(t_c, t_m, t_x)
+        "roofline_fraction": (mf / chips / TARGET["flops"]) / max(t_c, t_m, t_x)
         if max(t_c, t_m, t_x) else 0.0,
     })
     return res
@@ -166,4 +166,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the production meshes need 512 devices: virtual ones on the CPU
+    jax.config.update("jax_num_cpu_devices", 512)
     main()

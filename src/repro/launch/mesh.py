@@ -11,22 +11,6 @@ from __future__ import annotations
 import numpy as np
 
 
-def make_mesh_compat(shape, axes, devices=None):
-    """``jax.make_mesh`` across jax versions (added ~0.4.35; the oldest
-    supported pin predates it).  The fallback builds the Mesh directly from
-    the device array — equivalent for explicit host-platform device lists
-    (make_mesh's extra work is physical-topology-aware ordering, which has
-    no effect on CPU meshes)."""
-    import jax
-
-    if devices is None:
-        devices = jax.devices()[: int(np.prod(shape))]
-    mk = getattr(jax, "make_mesh", None)
-    if mk is not None:
-        return mk(shape, axes, devices=devices)
-    return jax.sharding.Mesh(np.asarray(devices).reshape(shape), axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     import jax
 
@@ -37,8 +21,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
-            "run under dryrun.py (it sets xla_force_host_platform_device_count)")
-    return make_mesh_compat(shape, axes, devices=devices[:n])
+            "run under dryrun.py (it gives the CPU backend 512 devices)")
+    return jax.make_mesh(shape, axes, devices=devices[:n])
 
 
 def batch_axes(mesh) -> tuple:

@@ -1,6 +1,6 @@
 """Roofline analysis from dry-run JSON artifacts (EXPERIMENTS.md §Roofline).
 
-Terms (per device, TPU v5e targets):
+Terms (per device, against the TPU v5e deployment target, ``TARGET``):
     compute    = HLO_FLOPs_per_device / 197e12          (bf16 MXU peak)
     memory     = HLO_bytes_per_device / 819e9           (HBM bandwidth)
     collective = collective_bytes_per_device / 50e9     (one ICI link, conservative)
@@ -21,9 +21,24 @@ import os
 
 from repro.configs import get_arch, get_shape
 
-PEAK_FLOPS = 197e12     # bf16 per chip
-HBM_BW = 819e9          # B/s per chip
-ICI_BW = 50e9           # B/s per link (conservative single-link)
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.  Source:
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s, 1,600 Gbit/s of ICI per chip over four links (50 GB/s is one
+#: link, the conservative per-hop figure).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+#: The chip the dry-run analyses (production meshes of v5e pods) target.
+TARGET = PEAKS["TPU v5 lite"]
+
+
+def device_peaks() -> dict | None:
+    """Peaks of the device this process runs on, or None when its
+    ``device_kind`` is not in :data:`PEAKS`: no roofline share is reported
+    for a device whose peaks are unknown."""
+    import jax
+
+    return PEAKS.get(jax.devices()[0].device_kind)
 
 
 # ------------------------------------------------- per-kernel cost model ----
@@ -74,11 +89,12 @@ def softmax_cost(rows: float, cols: float, *, code_bytes: float) -> dict:
     return {"flops": 5.0 * n, "bytes": 2.0 * n * code_bytes}
 
 
-def bound_times(flops: float, byts: float, coll_bytes: float = 0.0) -> dict:
-    """Roofline time terms for one dispatch (or one whole step) on the
-    TPU-v5e targets above, plus which term binds."""
-    terms = {"compute": flops / PEAK_FLOPS, "memory": byts / HBM_BW,
-             "collective": coll_bytes / ICI_BW}
+def bound_times(flops: float, byts: float, coll_bytes: float = 0.0,
+                peaks: dict = TARGET) -> dict:
+    """Roofline time terms for one dispatch (or one whole step) on a chip
+    with ``peaks`` (an entry of :data:`PEAKS`), plus which term binds."""
+    terms = {"compute": flops / peaks["flops"], "memory": byts / peaks["hbm_bw"],
+             "collective": coll_bytes / peaks["ici_bw"]}
     dominant = max(terms, key=terms.get)
     return {"t_compute_s": terms["compute"], "t_memory_s": terms["memory"],
             "t_collective_s": terms["collective"], "dominant": dominant,
@@ -172,7 +188,7 @@ def analyse(rec: dict, probe: dict | None = None) -> dict:
         "probe_corrected": bool(probe and not probe.get("error")),
         "roofline_fraction": (
             max(terms.values()) and
-            (mf / chips / PEAK_FLOPS) / max(terms.values())),
+            (mf / chips / TARGET["flops"]) / max(terms.values())),
     })
     return out
 

@@ -57,6 +57,7 @@ guessing by field names.
 from __future__ import annotations
 
 import argparse
+import collections
 import contextlib
 import json
 import sys
@@ -66,11 +67,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.launch.config import ServeConfig, add_cli_args, config_from_args
+from repro.launch.config import (ServeConfig, add_cli_args, config_from_args,
+                                 use_compile_cache)
 from repro.launch.engine import (KV_CONTAINERS as _KV_CONTAINERS, Request,
                                  poisson_requests)
 from repro.models.layers import policy_weight_bytes, quantize_params
-from repro.models.registry import build_model
 from repro.obs.metrics import percentile_ms
 
 
@@ -281,6 +282,9 @@ def _serve_continuous(args, cfg, model, params, policy, rng, S_max,
     report = {
         "mode": "continuous",
         "requests": len(completions),
+        "tokens": n_tokens,
+        "finish_reasons": dict(collections.Counter(
+            c.finish_reason for c in completions)),
         "max_slots": max_slots,
         "arrival_rate": args.arrival_rate,
         "decode_tok_per_s": round(n_tokens / makespan, 1),
@@ -352,20 +356,22 @@ def main(argv=None):
     run(args)
 
 
-def run(args: ServeConfig):
+def run(args: ServeConfig) -> dict:
     """Serve under a validated :class:`ServeConfig` (the programmatic entry
-    point — hillclimb and tests call this with a constructed config)."""
-    cfg = args.arch_cfg()
+    point — hillclimb and tests call this with a constructed config).
+    Returns the ``serve/report`` record it prints (without its kind)."""
     policy, drift_meta = args.build_policy()
-    model = build_model(cfg)
-    params = model.init(jax.random.key(args.seed))
+    model, params = args.load_model(policy)
+    cfg = model.cfg
     if args.calibrate:
         policy, cal_report = _calibrate(args, cfg, model, params, policy)
         drift_meta = {"meta": cal_report}
+        if args.quantize_weights:
+            params = quantize_params(params, policy)
     weight_report = {}
     if args.quantize_weights:
-        weight_report = policy_weight_bytes(params, policy)
-        params = quantize_params(params, policy)
+        weight_report = policy_weight_bytes(
+            jax.eval_shape(model.init, jax.random.key(args.seed)), policy)
     S_max = args.s_max(cfg)
 
     metrics, tracer, numerics = _build_observability(args, policy, drift_meta)
@@ -416,8 +422,7 @@ def run(args: ServeConfig):
                                 else "static")
 
         kv_b = kv_cache_bytes(cache)
-        print(json.dumps({
-            "kind": "serve/report",
+        out = {
             "arch": cfg.name, "policy": policy.describe(),
             **report,
             "kv_cache_bytes": kv_b,
@@ -425,7 +430,9 @@ def run(args: ServeConfig):
             "kv_bytes_per_token": kv_b // (n_rows * S_max),
             **weight_report,
             "config": args.to_json(),
-        }))
+        }
+        print(json.dumps({"kind": "serve/report", **out}))
+        return out
     finally:
         if metrics is not None:
             metrics.save(args.metrics_out)
@@ -436,4 +443,5 @@ def run(args: ServeConfig):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

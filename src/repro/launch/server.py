@@ -62,7 +62,8 @@ from typing import Optional
 
 import numpy as np
 
-from repro.launch.config import ServeConfig, add_cli_args, config_from_args
+from repro.launch.config import (ServeConfig, add_cli_args, config_from_args,
+                                 use_compile_cache)
 from repro.launch.engine import Request
 
 _WS_GUID = "258EAFA5-E914-47DA-95CA-C5AB0DC85B11"
@@ -80,6 +81,7 @@ class EngineDriver:
         self._stop = threading.Event()
         self._rid_lock = threading.Lock()
         self._next_rid = 0
+        self.errors = 0              # engine exceptions the drive loop survived
         self._thread = threading.Thread(target=self._drive, daemon=True,
                                         name="engine-drive")
         self._t0 = time.perf_counter()
@@ -116,6 +118,7 @@ class EngineDriver:
                 # one poisoned request must not kill serving for everyone:
                 # drop the queue head (admit raises before installing it),
                 # terminate its stream, keep driving
+                self.errors += 1
                 print(json.dumps({"kind": "server/error", "error": str(e)}),
                       flush=True)
                 if eng.queue:
@@ -170,7 +173,8 @@ class EngineDriver:
              "queued": len(eng.queue),
              "completions": len(eng.completions),
              "queue_depth": self.queue_depth(),
-             "max_queue": self.max_queue}
+             "max_queue": self.max_queue,
+             "errors": self.errors}
         if hasattr(eng, "prefix_stats"):
             d["prefix_cache"] = eng.prefix_stats()
         return d
@@ -457,15 +461,10 @@ async def _respond_text(writer, status: int, text: str,
 
 def build_server(scfg: ServeConfig) -> ServingServer:
     """Model + engine + server from one validated ServeConfig."""
-    import jax
-
-    from repro.models.registry import build_model
     from repro.obs.metrics import MetricsRegistry
 
-    cfg = scfg.arch_cfg()
     policy, _ = scfg.build_policy()
-    model = build_model(cfg)
-    params = model.init(jax.random.key(scfg.seed))
+    model, params = scfg.load_model(policy)
     metrics = MetricsRegistry()
     engine = scfg.build_engine(model, params, policy, metrics=metrics)
     return ServingServer(engine, scfg, metrics=metrics)
@@ -500,4 +499,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -35,14 +35,10 @@ from repro.core.pcsr import TransPolicy
 from repro.checkpoint.ckpt import CheckpointManager
 from repro.data.pipeline import SyntheticLMPipeline
 from repro.ft.runtime import FaultTolerantLoop, PreemptionSignal
+from repro.launch.config import use_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models.registry import build_model
 from repro.optim import AdamWConfig, adamw_init
-
-
-def _parse_policy(s: str) -> TransPolicy:
-    from repro.launch.dryrun import _parse_policy as pp
-    return pp(s)
 
 
 def main(argv=None):
@@ -76,7 +72,7 @@ def main(argv=None):
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    policy = _parse_policy(args.policy)
+    policy = TransPolicy.from_spec(args.policy)
     model = build_model(cfg)
     opt_cfg = AdamWConfig(lr=args.lr, moment_fmt=policy.optimizer)
 
@@ -242,4 +238,5 @@ def _profile_step(args, step_fn_raw, state, make_batch):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

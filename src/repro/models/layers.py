@@ -264,6 +264,25 @@ def quantize_params(params, policy):
     return out
 
 
+def init_quantized_params(init_fn, key, policy):
+    """``quantize_params(init_fn(key), policy)``, built one output leaf per
+    jitted program.
+
+    Each program computes only what its leaf needs, so the device never holds
+    more than one float leaf (one stacked layer weight) at a time: a model
+    whose f32 weights would not fit beside their posit codes still loads.
+    Bit-identical to ``quantize_params(init_fn(key), policy)`` when
+    ``init_fn`` is itself one jitted program (``Model.init`` is).
+    """
+    def build(k):
+        return quantize_params(init_fn(k), policy)
+
+    leaves, treedef = jax.tree.flatten(jax.eval_shape(build, key))
+    out = [jax.jit(lambda k, i=i: jax.tree.leaves(build(k))[i])(key)
+           for i in range(len(leaves))]
+    return jax.tree.unflatten(treedef, out)
+
+
 def _copy_dicts(tree):
     """Deep-copy the dict/list spine of a param tree (leaves shared)."""
     if isinstance(tree, dict):

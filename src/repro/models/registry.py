@@ -13,8 +13,18 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable
 
+import jax
+
 from repro.configs.base import ModelCfg
 from repro.models import encdec, transformer
+
+# Init is one XLA program per config.  XLA folds the constants of a traced
+# graph the same way whether it keeps every leaf or one, so the leaf-wise
+# serving loader (layers.init_quantized_params) is bit-identical to this
+# init; eager op-by-op init would differ from it in the last bit of scaled
+# weights.
+_init_lm = jax.jit(transformer.init_lm, static_argnums=1)
+_init_encdec = jax.jit(encdec.init_encdec, static_argnums=1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,7 +47,7 @@ def build_model(cfg: ModelCfg) -> Model:
     if cfg.family == "whisper":
         return Model(
             cfg=cfg,
-            init=lambda key: encdec.init_encdec(key, cfg),
+            init=lambda key: _init_encdec(key, cfg),
             loss=lambda p, b, pol: encdec.encdec_loss(p, b, cfg, pol),
             forward=lambda p, b, pol: encdec.decode_train(
                 p, b["tokens"], encdec.encode(p, b["frames"], cfg, pol), cfg, pol),
@@ -58,7 +68,7 @@ def build_model(cfg: ModelCfg) -> Model:
 
     return Model(
         cfg=cfg,
-        init=lambda key: transformer.init_lm(key, cfg),
+        init=lambda key: _init_lm(key, cfg),
         loss=loss,
         forward=fwd,
         init_cache=lambda B, S_max, pol: transformer.init_cache(cfg, B, S_max, pol),

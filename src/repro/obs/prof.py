@@ -131,23 +131,26 @@ class KernelRecord:
     bytes: float = 0.0
     seconds: float = 0.0     # measured wall time over eager calls
 
-    def to_dict(self) -> dict:
-        from repro.launch import roofline
-
-        bt = roofline.bound_times(self.flops, self.bytes)
+    def to_dict(self, peaks: Optional[dict]) -> dict:
         d = dataclasses.asdict(self)
-        d.update({
-            "t_compute_s": bt["t_compute_s"],
-            "t_memory_s": bt["t_memory_s"],
-            "bound": bt["dominant"],
-            "bound_s": bt["bound_s"],
-            # achieved-vs-bound: how far the measured time sits above the
-            # roofline floor (1.0 = at the bound; CPU interpret-mode runs
-            # sit far above it — the ratio is attribution, not a grade)
-            "achieved_frac": (bt["bound_s"] / self.seconds
-                              if self.seconds > 0 else None),
-        })
+        d.update(_bound_fields(self.flops, self.bytes, self.seconds, peaks))
         return d
+
+
+def _bound_fields(flops: float, byts: float, seconds: Optional[float],
+                  peaks: Optional[dict]) -> dict:
+    """Roofline floor of ``flops``/``byts`` on a chip with ``peaks``, and the
+    achieved-vs-bound ratio (1.0 = at the bound).  All None ("not
+    measured") when the running device has no entry in the peaks table."""
+    if peaks is None:
+        return dict.fromkeys(
+            ("t_compute_s", "t_memory_s", "bound", "bound_s", "achieved_frac"))
+    from repro.launch import roofline
+
+    bt = roofline.bound_times(flops, byts, peaks=peaks)
+    return {"t_compute_s": bt["t_compute_s"], "t_memory_s": bt["t_memory_s"],
+            "bound": bt["dominant"], "bound_s": bt["bound_s"],
+            "achieved_frac": bt["bound_s"] / seconds if seconds else None}
 
 
 _ACTIVE: Optional["KernelProfiler"] = None
@@ -216,6 +219,10 @@ def dispatch(family: str, impl: str, cost: dict, fn: Callable, *,
     return out
 
 
+def _us(seconds: Optional[float]) -> str:
+    return "not measured" if seconds is None else f"{seconds * 1e6:.2f}"
+
+
 class KernelProfiler:
     """Accumulates :class:`KernelRecord` rows keyed by (path, family, impl)."""
 
@@ -242,22 +249,22 @@ class KernelProfiler:
     def report(self, *, measured_total_s: Optional[float] = None) -> dict:
         from repro.launch import roofline
 
-        rows = [self.records[k].to_dict() for k in sorted(self.records)]
+        peaks = roofline.device_peaks()
+        rows = [self.records[k].to_dict(peaks) for k in sorted(self.records)]
         tot_flops = sum(r["flops"] for r in rows)
         tot_bytes = sum(r["bytes"] for r in rows)
-        bt = roofline.bound_times(tot_flops, tot_bytes)
+        bounds = _bound_fields(tot_flops, tot_bytes, measured_total_s, peaks)
         return {
             "version": 1,
             "kind": "repro/kernel-profile",
-            "peaks": {"flops": roofline.PEAK_FLOPS, "hbm_bw": roofline.HBM_BW},
+            "peaks": peaks,
             "rows": rows,
             "totals": {
                 "dispatches": sum(r["calls"] + r["traced"] for r in rows),
                 "flops": tot_flops, "bytes": tot_bytes,
-                "bound_s": bt["bound_s"], "bound": bt["dominant"],
+                "bound_s": bounds["bound_s"], "bound": bounds["bound"],
                 "measured_s": measured_total_s,
-                "achieved_frac": (bt["bound_s"] / measured_total_s
-                                  if measured_total_s else None),
+                "achieved_frac": bounds["achieved_frac"],
             },
         }
 
@@ -271,17 +278,21 @@ class KernelProfiler:
         for r in sorted(rep["rows"], key=lambda r: -r["bytes"]):
             lines.append(
                 "| {path} | {family} | {impl} | {calls} | {traced} "
-                "| {gf:.3f} | {mb:.3f} | {bound} | {bus:.2f} | {mus} |".format(
+                "| {gf:.3f} | {mb:.3f} | {bound} | {bus} | {mus} |".format(
                     path=r["path"] or "—", family=r["family"], impl=r["impl"],
                     calls=r["calls"], traced=r["traced"],
                     gf=r["flops"] / 1e9, mb=r["bytes"] / 1e6,
-                    bound=r["bound"], bus=r["bound_s"] * 1e6,
+                    bound=r["bound"] or "not measured",
+                    bus=_us(r["bound_s"]),
                     mus=(f"{r['seconds'] * 1e6:.1f}" if r["calls"] else "—")))
         t = rep["totals"]
+        floor = (f"{t['bound']}-bound floor {_us(t['bound_s'])} us"
+                 if t["bound"] else "roofline floor not measured (no peaks "
+                 "for this device)")
         lines.append(
             f"\ntotals: {t['dispatches']} dispatches, "
             f"{t['flops'] / 1e9:.3f} GFLOPs, {t['bytes'] / 1e6:.3f} MB, "
-            f"{t['bound']}-bound floor {t['bound_s'] * 1e6:.2f} us")
+            + floor)
         return "\n".join(lines)
 
     def save(self, path: str, *, measured_total_s: Optional[float] = None

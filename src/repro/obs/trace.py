@@ -15,8 +15,7 @@ Two aligned views of the same serving run (DESIGN.md §12):
   ``jax.profiler.TraceAnnotation`` (and :func:`named_scope` tags traced
   computations via ``jax.named_scope``), so a ``jax.profiler`` device trace
   captured alongside carries the same span names and lines up with the
-  request timeline.  Both degrade to no-ops when the profiler API is
-  missing (old jax) — tracing must never be the thing that breaks serving.
+  request timeline.
 
 All timestamps are seconds on the caller's monotonic clock
 (``time.perf_counter`` epoch); Chrome trace wants integer microseconds, the
@@ -24,7 +23,6 @@ conversion happens at serialization.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 from typing import Optional
 
@@ -34,17 +32,14 @@ __all__ = ["TraceRecorder", "annotate", "named_scope"]
 
 
 def annotate(name: str):
-    """Host-side profiler annotation around a dispatch (no-op without
-    jax.profiler support)."""
-    ta = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
-    return ta(name) if ta is not None else contextlib.nullcontext()
+    """Host-side profiler annotation around a dispatch."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 def named_scope(name: str):
     """Trace-time scope: tags the ops a traced function emits so device
-    profiles show ``name`` (no-op on jax versions without named_scope)."""
-    ns = getattr(jax, "named_scope", None)
-    return ns(name) if ns is not None else contextlib.nullcontext()
+    profiles show ``name``."""
+    return jax.named_scope(name)
 
 
 class TraceRecorder:

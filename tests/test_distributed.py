@@ -26,16 +26,8 @@ from repro.core import ref_codec
 from repro.distributed.collectives import (compressed_allreduce,
                                            compressed_psum, quire_psum_posit)
 
-# jax.shard_map + check_vma are the current API; fall back to the
-# experimental name + check_rep on older jax
-if hasattr(jax, "shard_map"):
-    _sm, _sm_kw = jax.shard_map, {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _sm
-    _sm_kw = {"check_rep": False}
-
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("pod", "data"))
+_sm, _sm_kw = jax.shard_map, {"check_vma": False}
+mesh = jax.make_mesh((2, 4), ("pod", "data"), devices=jax.devices()[:8])
 rng = np.random.default_rng(0)
 M = 1 << 14
 x = jnp.asarray(rng.normal(0, 1e-3, (8, M)).astype(np.float32))

@@ -2,15 +2,16 @@
 param counting, the dry-run cell driver — everything that doesn't need 512
 devices."""
 import dataclasses
+import os
 
 import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import cells, get_arch, get_shape
+from repro.core.pcsr import TransPolicy
 from repro.launch import dryrun
-from repro.launch.dryrun import cost_analysis_dict, parse_collectives
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.dryrun import parse_collectives
 from repro.launch.roofline import analyse, model_flops, param_count
 from repro.launch.sharding import param_spec
 
@@ -112,20 +113,28 @@ def test_model_flops_scaling():
     assert dc < pf / 1000
 
 
-def test_cost_analysis_dict_normalizes_list():
-    """Older jax returns cost_analysis() as a one-element list of dicts —
-    the run_cell AttributeError this helper fixes."""
-    class FakeCompiled:
-        def __init__(self, ret):
-            self._ret = ret
+@pytest.mark.parametrize("spec,want", [
+    ("none", {}),
+    ("p8-serve", {"weights": "p8_0", "kv_cache": "p8_0", "compute": "bf16"}),
+    ("weights=p16_1,kv=p8_0,compute=bf16",
+     {"weights": "p16_1", "kv_cache": "p8_0", "compute": "bf16"}),
+])
+def test_policy_spec_parse_leaves_xla_flags(monkeypatch, spec, want):
+    """Parsing a policy spec, and importing the serving and training entry
+    points, never rewrites the operator's ``XLA_FLAGS``."""
+    import importlib
 
-        def cost_analysis(self):
-            return self._ret
-
-    assert cost_analysis_dict(FakeCompiled({"flops": 1.0})) == {"flops": 1.0}
-    assert cost_analysis_dict(FakeCompiled([{"flops": 2.0}])) == {"flops": 2.0}
-    assert cost_analysis_dict(FakeCompiled([])) == {}
-    assert cost_analysis_dict(FakeCompiled(None)) == {}
+    monkeypatch.setenv("XLA_FLAGS", "--operator-flag")
+    for mod in ("repro.launch.serve", "repro.launch.server",
+                "repro.launch.train", "repro.launch.dryrun"):
+        importlib.import_module(mod)
+    pol = TransPolicy.from_spec(spec)
+    assert os.environ["XLA_FLAGS"] == "--operator-flag"
+    got = {r: getattr(pol, r).name for r in ("weights", "kv_cache")
+           if getattr(pol, r) is not None}
+    if pol.compute_dtype != "f32":
+        got["compute"] = pol.compute_dtype
+    assert got == want
 
 
 def test_dryrun_run_cell(monkeypatch):
@@ -135,14 +144,14 @@ def test_dryrun_run_cell(monkeypatch):
     cfg = get_arch("phi3-mini-3.8b").reduced()
     shape = dataclasses.replace(
         get_shape("decode_32k"), seq_len=64, global_batch=4)
-    mesh = make_mesh_compat((1, 1), ("data", "model"),
-                            devices=jax.devices()[:1])
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         devices=jax.devices()[:1])
     monkeypatch.setattr(dryrun, "get_arch", lambda name: cfg)
     monkeypatch.setattr(dryrun, "get_shape", lambda name: shape)
     monkeypatch.setattr(dryrun, "make_production_mesh",
                         lambda *, multi_pod: mesh)
     res = dryrun.run_cell("phi3-mini-3.8b", "decode_32k", multi_pod=False,
-                          policy=dryrun._parse_policy("p8-serve"))
+                          policy=TransPolicy.from_spec("p8-serve"))
     assert "error" not in res
     assert res["n_chips"] == 1
     assert res["flops_per_device"] >= 0

@@ -43,9 +43,7 @@ _BUCKET_RATIO = 10.0 ** 0.25
 
 def _drain_callbacks(out) -> None:
     jax.block_until_ready(out)
-    barrier = getattr(jax, "effects_barrier", None)
-    if barrier is not None:
-        barrier()
+    jax.effects_barrier()
 
 
 # ----------------------------------------------------------------- metrics ----

@@ -34,9 +34,7 @@ from repro.obs.train import JsonlStepLog, TrainingTelemetry
 
 def _drain_callbacks():
     """debug.callback effects are asynchronous; drain before reading stats."""
-    barrier = getattr(jax, "effects_barrier", None)
-    if barrier is not None:
-        barrier()
+    jax.effects_barrier()
 
 
 # ------------------------------------------------------------- grad observer --
@@ -168,7 +166,33 @@ def test_profiler_traced_vs_eager_dispatch():
     assert rec.traced == 1 and rec.calls == 1
     rep = profiler.report(measured_total_s=1.0)
     assert rep["totals"]["dispatches"] == 2
-    assert rep["rows"][0]["bound"] in ("compute", "memory")
+    # the CPU has no entry in the peaks table: no roofline share, never v5e's
+    assert rep["peaks"] is None
+    assert rep["rows"][0]["bound"] is None
+    assert rep["totals"]["achieved_frac"] is None
+    assert "not measured" in profiler.markdown(measured_total_s=1.0)
+
+
+def test_profiler_roofline_uses_running_device_peaks(monkeypatch):
+    """On a device in the peaks table the profiler reports its bound and
+    floor against that device's peaks."""
+    class V5e:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [V5e()])
+    x = jnp.ones((2, 8), jnp.float32)
+    p = init_linear(jax.random.PRNGKey(0), 8, 4)
+    profiler = prof.KernelProfiler()
+    with prof.profiling(profiler):
+        apply_linear(p, x, TransPolicy.from_names(), path="l")
+    rep = profiler.report(measured_total_s=1.0)
+    (row,) = rep["rows"]
+    assert rep["peaks"] == roofline.PEAKS["TPU v5 lite"]
+    assert row["bound"] in ("compute", "memory")
+    want = roofline.bound_times(row["flops"], row["bytes"],
+                                peaks=roofline.PEAKS["TPU v5 lite"])
+    assert row["bound_s"] == want["bound_s"]
+    assert rep["totals"]["achieved_frac"] == want["bound_s"] / 1.0
 
 
 def test_profiler_inactive_is_invisible():

@@ -165,8 +165,7 @@ def test_checkpoint_elastic_resharding(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     save_checkpoint(str(tmp_path), 1, tree)
-    from repro.launch.mesh import make_mesh_compat
-    mesh1 = make_mesh_compat((1,), ("data",))
+    mesh1 = jax.make_mesh((1,), ("data",), devices=jax.devices()[:1])
     sh = {"w": NamedSharding(mesh1, P("data", None))}
     restored, _ = load_checkpoint(str(tmp_path), tree, shardings=sh)
     assert (np.asarray(restored["w"]) == np.asarray(tree["w"])).all()
