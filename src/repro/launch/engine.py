@@ -279,27 +279,33 @@ class ContinuousBatchingEngine:
         stays valid across a swap.
         """
         model, S_max = self.model, self.S_max
+
+        # named functions, so profiles show jit_decode_step / jit_prefill
+        def decode_step(p, t, c):
+            return model.decode_step(p, t, c, policy)
+
+        def decode_step_probed(p, t, c):
+            return model.decode_step(p, t, c, policy)
+
+        def prefill(p, toks, kw):
+            return model.prefill(p, toks, policy, S_max=S_max, **kw)
+
         # the cache is donated: decode updates the KV buffers in place
         # instead of copying S_max-sized arrays every step (the engine never
         # reads a pre-step cache again; on backends without donation support
         # this degrades to the copy)
-        self._decode = jax.jit(
-            lambda p, t, c: model.decode_step(p, t, c, policy),
-            donate_argnums=(2,))
+        self._decode = jax.jit(decode_step, donate_argnums=(2,))
         # the numerics-probed twin: identical computation, but *traced* under
         # the watcher's observer so the per-site debug-callback reductions
         # bake into this executable only — the plain step stays probe-free
         # and the probe cost amortizes over the cadence (DESIGN.md §12)
         self._decode_probed = None
         if self.numerics is not None:
-            self._decode_probed = jax.jit(
-                lambda p, t, c: model.decode_step(p, t, c, policy),
-                donate_argnums=(2,))
+            self._decode_probed = jax.jit(decode_step_probed,
+                                          donate_argnums=(2,))
         # compiled per distinct prompt length (admission is on the serving
         # critical path; drivers bucket prompt lengths to bound retraces)
-        self._prefill = jax.jit(
-            lambda p, toks, kw: model.prefill(p, toks, policy,
-                                              S_max=S_max, **kw))
+        self._prefill = jax.jit(prefill)
 
     def apply_policy(self, policy) -> None:
         """Swap the serving policy mid-flight (degradation ladder step).
@@ -361,7 +367,7 @@ class ContinuousBatchingEngine:
                 buckets=tuple(float(b) for b in range(1, self.max_slots + 1)))
             self._m_occ = m.gauge("slot_occupancy", "live slots / max_slots")
             self._m_kv = m.gauge("kv_utilization",
-                                 "occupied KV rows / allocated rows")
+                                 "share of the KV store in use")
             self._m_queue = m.gauge("queue_depth", "requests waiting")
             self._m_rate = m.gauge("decode_tok_per_s_window",
                                    "tokens/s over the rolling 10s window")
@@ -592,15 +598,17 @@ class ContinuousBatchingEngine:
                 self.params, tokens, self._prefill_kwargs(req))
         # true cache occupancy after prefill (vlm rows include the patch
         # prefix; recurrent families report their prompt length)
-        row_len = int(one_cache["lens"][0])
-        if self.max_slots == 1:
-            # every leaf shape matches the B=1 prefill cache, so the
-            # structural scatter below would be a silent no-op — the
-            # single-request cache *is* the batch cache
-            self.cache = one_cache
-        else:
-            self.cache = self._write(self.cache, one_cache,
-                                     jnp.int32(slot))
+        with annotate("repro.admit.wait"):
+            row_len = int(one_cache["lens"][0])
+        with annotate("repro.admit.kv_scatter"):
+            if self.max_slots == 1:
+                # every leaf shape matches the B=1 prefill cache, so the
+                # structural scatter below would be a silent no-op — the
+                # single-request cache *is* the batch cache
+                self.cache = one_cache
+            else:
+                self.cache = self._write(self.cache, one_cache,
+                                         jnp.int32(slot))
         return logits, row_len
 
     def admit(self, now: float = 0.0, clock: Optional[Callable] = None) -> int:
@@ -619,55 +627,57 @@ class ContinuousBatchingEngine:
             if not self._can_admit(self.queue[0]):
                 break       # FIFO: later requests must not starve the head
             req = self.queue.pop(0)
-            t_admit = clock() if clock else now
-            if req.prompt_len + req.max_new_tokens > self.S_max:
-                raise ValueError(
-                    f"request {req.rid}: prompt {req.prompt_len} + "
-                    f"max_new {req.max_new_tokens} exceeds S_max {self.S_max}")
-            logits, row_len = self._prefill_into_slot(req, slot)
-            if row_len + req.max_new_tokens > self.S_max:
-                raise ValueError(
-                    f"request {req.rid}: prefill occupies {row_len} cache "
-                    f"rows (incl. any prefix) + max_new "
-                    f"{req.max_new_tokens} exceeds S_max {self.S_max}")
-            tok = int(self._next_token(logits)[0])  # blocks on the prefill
-            t_first = clock() if clock else now
-            self.lens[slot] = row_len
-            self.last_token = self.last_token.at[slot].set(tok)
-            self.active[slot] = True
-            self.slot_req[slot] = req
-            self.slot_tokens[slot] = []
-            self.slot_token_times[slot] = []
-            self.slot_admitted[slot] = t_admit
-            self._emit_token(slot, tok, t_first)
-            self._sync_lens()
+            with annotate("repro.admit", rid=req.rid):
+                t_admit = clock() if clock else now
+                if req.prompt_len + req.max_new_tokens > self.S_max:
+                    raise ValueError(
+                        f"request {req.rid}: prompt {req.prompt_len} + "
+                        f"max_new {req.max_new_tokens} exceeds S_max {self.S_max}")
+                logits, row_len = self._prefill_into_slot(req, slot)
+                if row_len + req.max_new_tokens > self.S_max:
+                    raise ValueError(
+                        f"request {req.rid}: prefill occupies {row_len} cache "
+                        f"rows (incl. any prefix) + max_new "
+                        f"{req.max_new_tokens} exceeds S_max {self.S_max}")
+                with annotate("repro.admit.wait"):
+                    tok = int(self._next_token(logits)[0])  # blocks on it
+                t_first = clock() if clock else now
+                self.lens[slot] = row_len
+                self.last_token = self.last_token.at[slot].set(tok)
+                self.active[slot] = True
+                self.slot_req[slot] = req
+                self.slot_tokens[slot] = []
+                self.slot_token_times[slot] = []
+                self.slot_admitted[slot] = t_admit
+                self._emit_token(slot, tok, t_first)
+                self._sync_lens()
+                if self.metrics is not None:
+                    self.metrics.counter(
+                        "requests_admitted",
+                        "requests prefilled into a slot").inc()
+                    self.metrics.counter("tokens_emitted",
+                                         "sampled tokens (prefill + decode)").inc()
+                    self.metrics.histogram(
+                        "queue_s", "arrival -> admission wait").observe(
+                            t_admit - req.arrival_time)
+                    self.metrics.histogram(
+                        "prefill_s", "admission -> first token").observe(
+                            t_first - t_admit)
+                    self.metrics.histogram(
+                        "ttft_s", "arrival -> first token").observe(
+                            t_first - req.arrival_time)
+                    if self._tok_rate is not None:
+                        self._tok_rate.add(t_first)
+                if self.tracer is not None:
+                    tid = slot + 1
+                    self.tracer.span(f"queued rid={req.rid}", req.arrival_time,
+                                     t_admit, tid=tid,
+                                     args={"rid": req.rid,
+                                           "prompt_len": req.prompt_len})
+                    self.tracer.span(f"prefill rid={req.rid}", t_admit, t_first,
+                                     tid=tid, args={"rid": req.rid})
+                self._maybe_finish(slot, tok, t_first)  # max_new_tokens == 1
             admitted += 1
-            if self.metrics is not None:
-                self.metrics.counter(
-                    "requests_admitted",
-                    "requests prefilled into a slot").inc()
-                self.metrics.counter("tokens_emitted",
-                                     "sampled tokens (prefill + decode)").inc()
-                self.metrics.histogram(
-                    "queue_s", "arrival -> admission wait").observe(
-                        t_admit - req.arrival_time)
-                self.metrics.histogram(
-                    "prefill_s", "admission -> first token").observe(
-                        t_first - t_admit)
-                self.metrics.histogram(
-                    "ttft_s", "arrival -> first token").observe(
-                        t_first - req.arrival_time)
-                if self._tok_rate is not None:
-                    self._tok_rate.add(t_first)
-            if self.tracer is not None:
-                tid = slot + 1
-                self.tracer.span(f"queued rid={req.rid}", req.arrival_time,
-                                 t_admit, tid=tid,
-                                 args={"rid": req.rid,
-                                       "prompt_len": req.prompt_len})
-                self.tracer.span(f"prefill rid={req.rid}", t_admit, t_first,
-                                 tid=tid, args={"rid": req.rid})
-            self._maybe_finish(slot, tok, t_first)  # max_new_tokens == 1
         return admitted
 
     def _next_token(self, logits):
@@ -686,17 +696,24 @@ class ContinuousBatchingEngine:
 
     # --------------------------------------------------------------- decode ---
     def step(self, now: float = 0.0) -> int:
-        """One decode step over the whole slot grid; returns #tokens emitted."""
+        """One decode step over the whole slot grid; returns #tokens emitted.
+
+        Its phases are profiler spans (``repro.step.*``, obs/trace.py), so a
+        device trace attributes the host's time between two decode steps."""
+        with annotate("repro.engine.step"):
+            return self._step(now)
+
+    def _step(self, now: float) -> int:
         self.last_now = max(self.last_now, now)
-        if self.faults is not None:
-            # chaos layer (repro.ft.serving.FaultPlan): may stall, inject
-            # NaR into KV pages, or raise preemption — before the decode so
-            # an injected fault is live in THIS step's computation
-            self.faults.on_step(self)
-        self._evict_expired(now)
-        if not self.active.any():
-            return 0
-        self._prepare_decode(now)
+        with annotate("repro.step.prepare"):
+            if self.faults is not None:
+                # chaos layer (repro.ft.serving.FaultPlan): may stall, inject
+                # NaR into KV pages, or raise preemption — before the decode
+                # so an injected fault is live in THIS step's computation
+                self.faults.on_step(self)
+            self._evict_expired(now)
+            if self.active.any():
+                self._prepare_decode(now)
         if not self.active.any():   # pool pressure may have evicted the rest
             return 0
         t0 = time.perf_counter()
@@ -714,35 +731,42 @@ class ContinuousBatchingEngine:
                 logits, self.cache = self._decode(self.params, self.last_token,
                                                   self.cache)
         self.steps += 1
-        toks = self._next_token(logits)
-        # nonfinite-logit quarantine (watchdog only — the reduction is an
-        # extra device op per step, so the bare engine never pays it): a slot
-        # whose logits went NaR is evicted as a partial Completion instead of
-        # sampling garbage, and its cache rows are scrubbed so the dead row
-        # cannot poison the shared grid or the numerics probes
-        bad = None
-        if self.watchdog is not None:
-            bad = np.asarray(jnp.any(~jnp.isfinite(logits), axis=-1))
-        self.lens += 1          # mirror decode_step's per-row increment
-        emitted = 0
-        toks_np = np.asarray(toks)
-        last_np = np.asarray(self.last_token).copy()
-        for slot in range(self.max_slots):
-            if not self.active[slot]:
-                continue
-            if bad is not None and bad[slot]:
-                self._quarantine(slot, now)
-                last_np[slot] = 0
-                continue
-            tok = int(toks_np[slot])
-            self._emit_token(slot, tok, now)
-            last_np[slot] = tok
-            emitted += 1
-            self._maybe_finish(slot, tok, now)
-        self.last_token = jnp.asarray(last_np)
-        self._observe_step(now, t0, emitted, probed)
-        if self.snapshotter is not None:
-            self.snapshotter.on_step(self)
+        with annotate("repro.step.sample"):
+            toks = self._next_token(logits)
+            # nonfinite-logit quarantine (watchdog only — the reduction is an
+            # extra device op per step, so the bare engine never pays it): a
+            # slot whose logits went NaR is evicted as a partial Completion
+            # instead of sampling garbage, and its cache rows are scrubbed so
+            # the dead row cannot poison the shared grid or the numerics
+            # probes
+            bad = None
+            if self.watchdog is not None:
+                bad = jnp.any(~jnp.isfinite(logits), axis=-1)
+        with annotate("repro.step.wait"):
+            toks_np = np.asarray(toks)
+            if bad is not None:
+                bad = np.asarray(bad)
+        with annotate("repro.step.emit"):
+            self.lens += 1      # mirror decode_step's per-row increment
+            emitted = 0
+            last_np = np.asarray(self.last_token).copy()
+            for slot in range(self.max_slots):
+                if not self.active[slot]:
+                    continue
+                if bad is not None and bad[slot]:
+                    self._quarantine(slot, now)
+                    last_np[slot] = 0
+                    continue
+                tok = int(toks_np[slot])
+                self._emit_token(slot, tok, now)
+                last_np[slot] = tok
+                emitted += 1
+                self._maybe_finish(slot, tok, now)
+            self.last_token = jnp.asarray(last_np)
+        with annotate("repro.step.observe"):
+            self._observe_step(now, t0, emitted, probed)
+            if self.snapshotter is not None:
+                self.snapshotter.on_step(self)
         return emitted
 
     def _prepare_decode(self, now: float) -> None:
@@ -828,8 +852,7 @@ class ContinuousBatchingEngine:
             self._m_step_s.observe(dt)
             self._m_slots.observe(n_active)
             self._m_occ.set(n_active / self.max_slots)
-            self._m_kv.set(int(self.lens.sum())
-                           / (self.max_slots * self.S_max))
+            self._m_kv.set(self._kv_utilization())
             self._m_queue.set(len(self.queue))
             self._tok_rate.add(now, emitted)
             self._m_rate.set(self._tok_rate.rate(now))
@@ -839,6 +862,11 @@ class ContinuousBatchingEngine:
             self.tracer.span("decode_step", t0, time.perf_counter(),
                              tid=0, args={"emitted": emitted,
                                           "probed": probed})
+
+    def _kv_utilization(self) -> float:
+        """Share of the KV store in use: occupied rows over allocated rows
+        (the paged engine counts pool blocks instead)."""
+        return int(self.lens.sum()) / (self.max_slots * self.S_max)
 
     def _maybe_finish(self, slot: int, tok: int, now: float) -> bool:
         req = self.slot_req[slot]

@@ -140,20 +140,26 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
     def _build_executables(self, policy) -> None:
         model, S_max = self.model, self.S_max
-        self._decode = jax.jit(
-            lambda p, t, c: model.decode_step_paged(p, t, c, policy),
-            donate_argnums=(2,))
-        self._decode_probed = None
-        if self.numerics is not None:
-            self._decode_probed = jax.jit(
-                lambda p, t, c: model.decode_step_paged(p, t, c, policy),
-                donate_argnums=(2,))
+
+        # named functions, so profiles show jit_decode_step / jit_prefill
+        def decode_step(p, t, c):
+            return model.decode_step_paged(p, t, c, policy)
+
+        def decode_step_probed(p, t, c):
+            return model.decode_step_paged(p, t, c, policy)
+
         # prefill stays the slot-grid program: it writes a dense B=1 strip
         # whose chunks are then scattered into pool blocks (full prefill is
         # the warm≡cold exactness contract — see the module docstring)
-        self._prefill = jax.jit(
-            lambda p, toks, kw: model.prefill(p, toks, policy,
-                                              S_max=S_max, **kw))
+        def prefill(p, toks, kw):
+            return model.prefill(p, toks, policy, S_max=S_max, **kw)
+
+        self._decode = jax.jit(decode_step, donate_argnums=(2,))
+        self._decode_probed = None
+        if self.numerics is not None:
+            self._decode_probed = jax.jit(decode_step_probed,
+                                          donate_argnums=(2,))
+        self._prefill = jax.jit(prefill)
 
     def _init_cache(self):
         return self.model.init_paged_cache(
@@ -223,42 +229,44 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         with annotate("repro.prefill"):
             logits, one_cache = self._prefill(
                 self.params, tokens, self._prefill_kwargs(req))
-        row_len = int(one_cache["lens"][0])
-        mgr.claim_blocks(match.bids)
-        mgr.begin_slot(slot, match.bids)
-        if match.bids:
-            mgr.hits += 1
-            mgr.hit_tokens += match.n_tokens
-            if self.metrics is not None:
-                self._m_prefix_hits.inc()
-                self._m_prefix_tokens.inc(match.n_tokens)
-        else:
-            mgr.misses += 1
-        digests = mgr.chunk_digests(req.prompt)
-        parent = match.tail_digest
-        one_kv = one_cache["kv"]
-        pos = match.n_tokens
-        while pos < row_len:
-            n = min(bt, row_len - pos)
-            try:
-                bid = mgr.append_block(slot)
-            except PoolExhausted:
-                # _can_admit budgeted for this prompt, but a COW burst in
-                # the same step can race it; unwind and retry later
-                mgr.release_slot(slot)
-                raise
-            kv = self.cache["kv"]
-            kv["k"] = _copy_span(kv["k"], one_kv["k"], jnp.int32(bid),
-                                 jnp.int32(pos), n)
-            kv["v"] = _copy_span(kv["v"], one_kv["v"], jnp.int32(bid),
-                                 jnp.int32(pos), n)
-            if n == bt:
-                digest, chunk = digests[pos // bt]
-                mgr.register_full_block(bid, digest, parent, chunk)
-                parent = digest
-            pos += n
-        self._table_dirty = True
-        self._push_table()
+        with annotate("repro.admit.wait"):
+            row_len = int(one_cache["lens"][0])
+        with annotate("repro.admit.kv_scatter"):
+            mgr.claim_blocks(match.bids)
+            mgr.begin_slot(slot, match.bids)
+            if match.bids:
+                mgr.hits += 1
+                mgr.hit_tokens += match.n_tokens
+                if self.metrics is not None:
+                    self._m_prefix_hits.inc()
+                    self._m_prefix_tokens.inc(match.n_tokens)
+            else:
+                mgr.misses += 1
+            digests = mgr.chunk_digests(req.prompt)
+            parent = match.tail_digest
+            one_kv = one_cache["kv"]
+            pos = match.n_tokens
+            while pos < row_len:
+                n = min(bt, row_len - pos)
+                try:
+                    bid = mgr.append_block(slot)
+                except PoolExhausted:
+                    # _can_admit budgeted for this prompt, but a COW burst in
+                    # the same step can race it; unwind and retry later
+                    mgr.release_slot(slot)
+                    raise
+                kv = self.cache["kv"]
+                kv["k"] = _copy_span(kv["k"], one_kv["k"], jnp.int32(bid),
+                                     jnp.int32(pos), n)
+                kv["v"] = _copy_span(kv["v"], one_kv["v"], jnp.int32(bid),
+                                     jnp.int32(pos), n)
+                if n == bt:
+                    digest, chunk = digests[pos // bt]
+                    mgr.register_full_block(bid, digest, parent, chunk)
+                    parent = digest
+                pos += n
+            self._table_dirty = True
+            self._push_table()
         return logits, row_len
 
     # --------------------------------------------------------------- decode ---
@@ -306,6 +314,10 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             self.cache["table"] = jnp.asarray(
                 self.manager.device_table(self.table_width))
             self._table_dirty = False
+
+    def _kv_utilization(self) -> float:
+        """Pool blocks held by live slots over the pool's blocks."""
+        return int((self.manager.refcount > 0).sum()) / self.n_blocks
 
     # ------------------------------------------------------------- eviction ---
     def _release_slot(self, slot: int) -> None:

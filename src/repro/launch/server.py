@@ -65,6 +65,7 @@ import numpy as np
 from repro.launch.config import (ServeConfig, add_cli_args, config_from_args,
                                  use_compile_cache)
 from repro.launch.engine import Request
+from repro.obs.trace import annotate
 
 _WS_GUID = "258EAFA5-E914-47DA-95CA-C5AB0DC85B11"
 
@@ -100,13 +101,14 @@ class EngineDriver:
         eng = self.engine
         while not self._stop.is_set():
             progressed = False
-            while True:
-                try:
-                    op = self._ops.get_nowait()
-                except queue_mod.Empty:
-                    break
-                op(self.clock())
-                progressed = True
+            with annotate("repro.drive.ops"):
+                while True:
+                    try:
+                        op = self._ops.get_nowait()
+                    except queue_mod.Empty:
+                        break
+                    op(self.clock())
+                    progressed = True
             try:
                 if eng.queue and eng.free_slots():
                     eng.admit(clock=self.clock)

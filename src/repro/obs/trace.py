@@ -11,11 +11,42 @@ Two aligned views of the same serving run (DESIGN.md §12):
   (queue wait, prefill, decode) renders as contiguous spans on the slot row
   it was admitted to, so slot churn / occupancy gaps are visible at a
   glance.
-* **Device scopes** — :func:`annotate` wraps host-side dispatches in
-  ``jax.profiler.TraceAnnotation`` (and :func:`named_scope` tags traced
-  computations via ``jax.named_scope``), so a ``jax.profiler`` device trace
-  captured alongside carries the same span names and lines up with the
-  request timeline.
+* **Profiler spans** — :func:`annotate` opens a
+  ``jax.profiler.TraceAnnotation``, a host span on the same clock as the
+  device's operations in a ``jax.profiler`` trace (and :func:`named_scope`
+  tags traced computations via ``jax.named_scope``).  With no profiler
+  session open a span costs about a microsecond.
+
+Profiler span vocabulary, all opened on the engine's drive thread (the
+``repro.`` prefix is what the benchmark's trace reduction keeps):
+
+=========================  ==================================================
+``repro.engine.step``      the whole of ``engine.step()`` (one decode step)
+``repro.step.prepare``     deadline evictions, block appends, copy-on-write
+                           and the block-table push before the dispatch
+``repro.decode_step``      the decode program's dispatch (``repro.
+                           decode_probed`` for the numerics-probed twin)
+``repro.step.sample``      the sampling program's dispatch
+``repro.step.wait``        ``np.asarray(toks)``: the host blocked on the
+                           decode and sampling programs
+``repro.step.emit``        the per-slot emit/finish/evict loop and the
+                           ``last_token`` round trip
+``repro.step.observe``     the metrics/trace feed and the snapshotter hook
+``repro.drive.ops``        one drain of the server's client-op inbox
+``repro.admit``            one admission, prefill to emitted first token
+                           (``rid=`` argument)
+``repro.prefill``          the B=1 prefill program's dispatch
+``repro.admit.kv_scatter`` the prefill rows' scatter into the batch cache
+                           (pool blocks and table push, or the slot row)
+``repro.admit.wait``       the host blocked on the prefill (row length,
+                           first token)
+=========================  ==================================================
+
+The benchmark reads these by name (``bench/trace_reduce.py`` attributes
+programs to ``repro.decode_step`` / ``repro.prefill``; ``step_host_ms``
+reads ``repro.decode_step``, ``repro.step.wait`` and ``repro.admit``; idle
+gaps carry the innermost span open at their midpoint), so a span that the
+benchmark reads keeps its name and what it wraps.
 
 All timestamps are seconds on the caller's monotonic clock
 (``time.perf_counter`` epoch); Chrome trace wants integer microseconds, the
@@ -31,9 +62,9 @@ import jax
 __all__ = ["TraceRecorder", "annotate", "named_scope"]
 
 
-def annotate(name: str):
-    """Host-side profiler annotation around a dispatch."""
-    return jax.profiler.TraceAnnotation(name)
+def annotate(name: str, **args):
+    """Host-side profiler span (``args`` ride on the event, not its name)."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def named_scope(name: str):
