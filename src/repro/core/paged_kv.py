@@ -108,10 +108,13 @@ class PageGeometry:
     def describe(self) -> str:
         """Config fingerprint line — part of the snapshot compatibility
         check (ft/serving.py): a snapshot taken under one page geometry must
-        never restore into another."""
+        never restore into another.  ``layout`` names the device pool's axis
+        order, ``(N, bt, Hkv*hd)`` per layer: a pool of another layout holds
+        as many codes under the same leaf path, so only this line tells
+        them apart."""
         return (f"paged(bt={self.block_tokens},L={self.n_layers},"
                 f"kv={self.n_kv}x{self.head_dim},code_B={self.code_bytes},"
-                f"page_B={self.page_bytes})")
+                f"page_B={self.page_bytes},layout=bt,kv*hd)")
 
 
 @dataclasses.dataclass
@@ -126,7 +129,7 @@ class PrefixMatch:
 class PagedKVCache:
     """Host-side allocator for one block pool (refcounts, hashes, tables).
 
-    Block ids index the device pools ``(L, n_blocks, Hkv, bt, hd)``; the
+    Block ids index the device pools ``(L, n_blocks, bt, Hkv*hd)``; the
     sentinel id ``n_blocks`` marks empty table entries (out-of-bounds on
     device, so scatters through it drop and gathers clamp into masked-off
     rows).
@@ -333,7 +336,10 @@ class PagedKVCache:
             "misses": self.misses, "cow_copies": self.cow_copies,
         }
 
-    def restore_meta(self, meta: dict) -> None:
+    def check_meta(self, meta: dict) -> None:
+        """Refuse a snapshot taken under another page geometry or pool
+        size; it changes nothing, so a caller can check before it installs
+        anything."""
         if meta["geometry"] != self.geom.describe():
             raise ValueError(
                 f"snapshot page geometry {meta['geometry']} does not match "
@@ -342,6 +348,9 @@ class PagedKVCache:
             raise ValueError(
                 f"snapshot pool has {meta['n_blocks']} blocks, engine has "
                 f"{self.n_blocks}")
+
+    def restore_meta(self, meta: dict) -> None:
+        self.check_meta(meta)
         self.refcount = np.asarray(meta["refcount"], np.int32)
         self.free = list(meta["free"])
         self.lru = OrderedDict((int(b), None) for b in meta["lru"])

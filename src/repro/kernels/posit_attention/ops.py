@@ -14,6 +14,12 @@ cache is never materialized in float):
   softmax.  Numerics ground truth for tests.
 * ``auto``   — pallas on TPU, tiled elsewhere.
 
+The paged engine's pool has its own front door,
+:func:`posit_decode_attention_paged` (same contract, KV reached through a
+block table): on a TPU the Pallas kernel in paged_attention.py reads only
+each row's live pages from the pool; off-TPU one gather de-pages each row's
+block-table window and runs the tiled path.
+
 ``kv_bits=0`` means a float KV cache: every path bypasses the codec and
 just upcasts tiles (the ragged masking / tiling contract is unchanged).
 ``rolling=True`` is circular-buffer validity (gemma3 local layers): every
@@ -27,6 +33,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.codec import posit_decode
+from repro.kernels.posit_attention.paged_attention import (
+    posit_decode_attention_paged_pallas,
+)
 from repro.kernels.posit_attention.posit_attention import posit_decode_attention
 from repro.kernels.posit_attention.ref import posit_decode_attention_ref
 
@@ -105,10 +114,10 @@ def posit_decode_attention_tiled(
 
 
 @functools.partial(jax.jit, static_argnames=("kv_bits", "scale"))
-def posit_decode_attention_paged(
+def posit_decode_attention_paged_gather(
     q: jax.Array,            # (B, Hq, d) float
-    k_pool: jax.Array,       # (N_blocks, Hkv, bt, d) posit codes (one layer)
-    v_pool: jax.Array,       # (N_blocks, Hkv, bt, d)
+    k_pool: jax.Array,       # (N_blocks, bt, Hkv*d) posit codes (one layer)
+    v_pool: jax.Array,       # (N_blocks, bt, Hkv*d)
     block_table: jax.Array,  # (B, W) int32 block ids; >= N_blocks = empty
     lengths: jax.Array,      # (B,) int32 — valid KV length per batch row
     es,                      # int32 scalar — pcsr pes for the KV cache
@@ -116,17 +125,16 @@ def posit_decode_attention_paged(
     kv_bits: int,
     scale: float | None = None,
 ) -> jax.Array:
-    """The indirection-aware sibling of :func:`posit_decode_attention_tiled`.
+    """The off-TPU lowering of :func:`posit_decode_attention_paged`.
 
-    Lowering: ONE batched gather de-pages each row's block-table window into
-    a contiguous ``(B, Hkv, W*bt, d)`` code view, which then runs the exact
-    tiled online-softmax above — the same compiled attention the slot grid
-    uses, at the same (wide) tile size.  The earlier lowering looped the
-    online softmax block-by-block (``bt``-sized tiles), which is the right
-    shape for a Pallas TPU kernel but ~2x slower in XLA:CPU, where 16-token
-    tiles are dispatch-dominated; hoisting the indirection into one gather
-    restores grid-path decode cost and makes warm-vs-cold bit-identity
-    structural rather than empirical.
+    ONE batched gather de-pages each row's whole block-table window into a
+    contiguous ``(B, Hkv, W*bt, d)`` code view, which then runs the tiled
+    online-softmax above — the same compiled attention the slot grid uses
+    off-TPU, at the same (wide) tile size.  In XLA:CPU, 16-token page tiles
+    are dispatch-dominated, so hoisting the indirection into one gather is
+    the cheap shape there, and it makes warm-vs-cold bit-identity structural
+    rather than empirical.  On a TPU the gather copies every row's whole
+    window, live or not, and the Pallas kernel replaces it.
 
     Table entries past a row's length are sentinels (``>= N_blocks``); their
     clamped gather reads whatever lives in an arbitrary real block, so
@@ -136,22 +144,58 @@ def posit_decode_attention_paged(
     *codes* suffices: code 0 decodes to exact 0.0 in every posit config
     (and is 0.0 already when ``kv_bits == 0``).
     """
-    B = q.shape[0]
-    N, Hkv, bt, d = k_pool.shape
+    B, _, d = q.shape
+    N, bt, F = k_pool.shape
+    Hkv = F // d
     W = block_table.shape[1]
     lengths = jnp.asarray(lengths, jnp.int32)
     table = jnp.minimum(jnp.asarray(block_table, jnp.int32), N - 1)
 
     def depage(pool):
-        codes = pool[table]                        # (B, W, Hkv, bt, d)
-        codes = jnp.moveaxis(codes, 2, 1)          # (B, Hkv, W, bt, d)
-        return codes.reshape(B, Hkv, W * bt, d)
+        codes = pool[table]                        # (B, W, bt, Hkv*d)
+        codes = codes.reshape(B, W * bt, Hkv, d)
+        return jnp.moveaxis(codes, 2, 1)           # (B, Hkv, W*bt, d)
 
     valid = (jnp.arange(W * bt)[None, :] < lengths[:, None])[:, None, :, None]
     k_codes = jnp.where(valid, depage(k_pool), 0)
     v_codes = jnp.where(valid, depage(v_pool), 0)
     return posit_decode_attention_tiled(q, k_codes, v_codes, lengths, es,
                                         kv_bits=kv_bits, scale=scale)
+
+
+def posit_decode_attention_paged(q, k_pool, v_pool, block_table, lengths, es,
+                                 *, kv_bits, scale=None, impl="auto"):
+    """Decode attention over one layer's paged pool (DESIGN.md §14).
+
+    ``k_pool``/``v_pool``: ``(N_blocks, bt, Hkv*d)`` codes; ``block_table``
+    ``(B, W)`` block ids, sentinels (``>= N_blocks``) past each row's
+    length; ``lengths`` the valid length per row.  ``impl`` is the
+    policy's ``attn_impl``.  ``"auto"`` and ``"kernel"`` run the Pallas
+    kernel (paged_attention.py: only each row's live pages, read through
+    the table) on a TPU, for pages of whole sublane rows (``bt % 8 == 0``);
+    the one-gather lowering (:func:`posit_decode_attention_paged_gather`)
+    runs everywhere else, so CPU results stay what the gather gives.  On a
+    TPU, ``"kernel"`` with pages the kernel cannot take raises rather than
+    quietly measure the gather; ``"auto"`` falls back to it.  ``"xla"``
+    always takes the gather.  The ``named_scope`` tag names the path a
+    device profile saw.
+    """
+    from repro.obs.trace import named_scope
+
+    bt = k_pool.shape[1]
+    if impl == "kernel" and _on_tpu() and bt % 8:
+        raise ValueError(
+            f"attn_impl='kernel': the paged kernel needs pages of a multiple "
+            f"of 8 tokens, these hold {bt}; use attn_impl='auto' or 'xla'")
+    if impl in ("auto", "kernel") and _on_tpu() and bt % 8 == 0:
+        with named_scope("repro.decode_attention.paged_pallas"):
+            return posit_decode_attention_paged_pallas(
+                q, k_pool, v_pool, block_table, lengths, es, kv_bits=kv_bits,
+                scale=scale)
+    with named_scope("repro.decode_attention.paged_gather"):
+        return posit_decode_attention_paged_gather(
+            q, k_pool, v_pool, block_table, lengths, es, kv_bits=kv_bits,
+            scale=scale)
 
 
 def decode_attention(q, k_codes, v_codes, lengths, es, *, kv_bits,

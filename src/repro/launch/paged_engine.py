@@ -6,10 +6,12 @@ rows past a request's live length are dead bytes, and requests sharing a
 system prompt store the same prefix codes once *per slot*.  This subclass
 swaps the strips for fixed-byte pages (``core.paged_kv``, DESIGN.md §14):
 
-* the device cache is one block pool per layer ``(L, N, Hkv, bt, hd)`` plus
-  a per-slot block table ``(max_slots, W)``; attention reads gather tiles
-  through the table (``kernels.posit_attention.posit_decode_attention_paged``)
-  and decode writes scatter into ``table[b, lens[b] // bt]``;
+* the device cache is one block pool per layer ``(L, N, bt, Hkv*hd)`` plus
+  a per-slot block table ``(max_slots, W)``; attention reads go through the
+  table (``kernels.posit_attention.ops.posit_decode_attention_paged``: on a
+  TPU a Pallas kernel copies only each row's live pages from the pool; the
+  one-gather lowering of each row's whole window is the off-TPU path) and
+  decode writes scatter into ``table[b, lens[b] // bt]``;
 * admission content-addresses every *full* prefill block by a chained
   blake2b over its token prefix — a request whose prompt starts with an
   already-cached chain claims those blocks (refcount++) instead of storing
@@ -55,29 +57,30 @@ __all__ = ["PagedContinuousBatchingEngine"]
 def _copy_span(pool_arr, one_arr, bid, start, n):
     """Copy ``n`` KV rows from a B=1 prefill cache into block ``bid``.
 
-    ``pool_arr``: (L, N, Hkv, bt, hd); ``one_arr``: (L, 1, Hkv, S, hd).
+    ``pool_arr``: (L, N, bt, Hkv*hd); ``one_arr``: (L, 1, Hkv, S, hd).
     ``n`` is static (one compile per distinct tail size — prompt-length
     buckets keep that bounded); ``start``/``bid`` are traced so full chunks
     of any position share one program.
     """
+    L, _, Hkv, _, hd = one_arr.shape
     chunk = jax.lax.dynamic_slice_in_dim(one_arr[:, 0], start, n, axis=2)
-    return jax.lax.dynamic_update_slice(
-        pool_arr, chunk[:, None], (0, bid, 0, 0, 0))
+    chunk = jnp.moveaxis(chunk, 1, 2).reshape(L, 1, n, Hkv * hd)
+    return jax.lax.dynamic_update_slice(pool_arr, chunk, (0, bid, 0, 0))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _copy_block(pool_arr, src, dst):
     """Device copy-on-write: clone block ``src`` into ``dst`` (all layers)."""
     row = jax.lax.dynamic_slice_in_dim(pool_arr, src, 1, axis=1)
-    return jax.lax.dynamic_update_slice(pool_arr, row, (0, dst, 0, 0, 0))
+    return jax.lax.dynamic_update_slice(pool_arr, row, (0, dst, 0, 0))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("n",))
 def _poison_block(pool_arr, bid, code, n):
     """Overwrite the first ``n`` rows of block ``bid`` with ``code``."""
-    L, _, Hkv, _, hd = pool_arr.shape
-    bad = jnp.full((L, 1, Hkv, n, hd), code, pool_arr.dtype)
-    return jax.lax.dynamic_update_slice(pool_arr, bad, (0, bid, 0, 0, 0))
+    L, _, _, F = pool_arr.shape
+    bad = jnp.full((L, 1, n, F), code, pool_arr.dtype)
+    return jax.lax.dynamic_update_slice(pool_arr, bad, (0, bid, 0, 0))
 
 
 class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
@@ -412,6 +415,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             raise ValueError(
                 "snapshot has no paged-cache state (taken by a slot-grid "
                 "engine?) — it cannot restore into a paged engine")
+        # before anything is installed: a pool of another page layout has
+        # as many codes and would reshape into this one without complaint
+        self.manager.check_meta(snap["meta"]["paged"])
         super().restore(snap, now=now)
         self.manager.restore_meta(snap["meta"]["paged"])
         self._table_dirty = True

@@ -266,23 +266,28 @@ def prefill_attention(params: dict, cfg: AttnCfg, x: jax.Array, cache: dict,
 
 def init_paged_kv_pool(n_blocks: int, block_tokens: int, cfg: AttnCfg,
                        policy: TransPolicy) -> dict:
-    """One layer's paged KV pool: ``(n_blocks, Hkv, block_tokens, hd)`` codes.
+    """One layer's paged KV pool: ``(n_blocks, block_tokens, Hkv*hd)`` codes.
 
-    Same dtype rule as :func:`init_kv_cache`; the per-slot ``len`` lives with
-    the engine (``cache["lens"]``), and the block table is shared across
-    layers — every layer of a slot uses the same block ids (DESIGN.md §14).
+    A page is ``block_tokens`` token rows with every KV head side by side:
+    the row is lane-dense whatever ``hd`` is, so the TPU keeps the pool
+    row-major and a page is one contiguous copy (a ``(..., bt, hd)`` minor
+    tile with ``hd`` 96 would pad to 128 lanes, and the TPU then lays the
+    block axis out minor instead).  Same dtype rule as
+    :func:`init_kv_cache`; the per-slot ``len`` lives with the engine
+    (``cache["lens"]``), and the block table is shared across layers —
+    every layer of a slot uses the same block ids (DESIGN.md §14).
     """
     fmt = policy.kv_cache
     if fmt is not None:
         dt = jnp.uint8 if fmt.nbits == 8 else jnp.uint16
     else:
         dt = jnp.float32 if policy.compute_dtype == "f32" else jnp.bfloat16
-    shape = (n_blocks, cfg.n_kv, block_tokens, cfg.head_dim)
+    shape = (n_blocks, block_tokens, cfg.n_kv * cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
 def _store_paged(pool_arr, new, bids, offs, policy):
-    """Scatter (B, Hkv, 1, hd) ``new`` into ``pool[bids[b], :, offs[b]]``.
+    """Scatter (B, Hkv*hd) ``new`` into ``pool[bids[b], offs[b]]``.
 
     Sentinel block ids (>= n_blocks) drop the write — inactive slots point
     every table entry out of bounds, so the lockstep grid step is a no-op
@@ -295,9 +300,7 @@ def _store_paged(pool_arr, new, bids, offs, policy):
         new = new.astype(pool_arr.dtype)
     bids = jnp.asarray(bids, jnp.int32)
     offs = jnp.asarray(offs, jnp.int32)
-    # advanced indices (bids, offs) straddle the ':' so the joint batch axis
-    # moves to front: target (B, Hkv, hd) matches new[:, :, 0]
-    return pool_arr.at[bids, :, offs].set(new[:, :, 0], mode="drop")
+    return pool_arr.at[bids, offs].set(new, mode="drop")
 
 
 def decode_attention_step_paged(params: dict, cfg: AttnCfg, x_t: jax.Array,
@@ -307,16 +310,17 @@ def decode_attention_step_paged(params: dict, cfg: AttnCfg, x_t: jax.Array,
     """One decode step over a paged KV pool (DESIGN.md §14).
 
     ``pool`` holds one layer's ``{"k", "v"}`` block arrays
-    ``(N, Hkv, bt, hd)``; ``block_table`` is the slot grid's ``(B, W)``
+    ``(N, bt, Hkv*hd)``; ``block_table`` is the slot grid's ``(B, W)``
     indirection and ``lens`` the per-row write index (= valid length before
     this token).  The engine guarantees the write target
     ``block_table[b, lens[b] // bt]`` is a *private* block (copy-on-write
     runs before the step), so no two rows ever scatter into the same page.
-    Attention reads route through the indirection-aware tiled kernel.
+    Attention reads go through the table: the Pallas paged kernel on a TPU,
+    the one-gather tiled path elsewhere or under ``attn_impl="xla"``.
     """
     B, _, _ = x_t.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv, cfg.head_dim
-    bt = pool["k"].shape[2]
+    bt = pool["k"].shape[1]
     q = _split_heads(apply_linear(params["wq"], x_t, policy,
                                   path=f"{path}/wq"), H, hd)
     kn = _split_heads(apply_linear(params["wk"], x_t, policy,
@@ -332,16 +336,17 @@ def decode_attention_step_paged(params: dict, cfg: AttnCfg, x_t: jax.Array,
                                (lens // bt)[:, None], axis=1)[:, 0]
     offs = lens % bt
     new_pool = dict(pool)
-    new_pool["k"] = _store_paged(pool["k"], kn.transpose(0, 2, 1, 3),
+    new_pool["k"] = _store_paged(pool["k"], kn.reshape(B, Hkv * hd),
                                  bids, offs, policy)
-    new_pool["v"] = _store_paged(pool["v"], vn.transpose(0, 2, 1, 3),
+    new_pool["v"] = _store_paged(pool["v"], vn.reshape(B, Hkv * hd),
                                  bids, offs, policy)
     fmt = policy.kv_cache
     with prof.site(path):
         out = attn_ops.posit_decode_attention_paged(
             q.reshape(B, H, hd), new_pool["k"], new_pool["v"], block_table,
             lens + 1, fmt.es if fmt is not None else 0,
-            kv_bits=fmt.nbits if fmt is not None else 0)
+            kv_bits=fmt.nbits if fmt is not None else 0,
+            impl=getattr(policy, "attn_impl", "auto"))
     y = apply_linear(params["wo"], out.reshape(B, 1, H * hd).astype(x_t.dtype),
                      policy, path=f"{path}/wo")
     return y, new_pool

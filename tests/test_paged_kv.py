@@ -256,6 +256,23 @@ def test_snapshot_meta_round_trip_and_geometry_guard():
         small.restore_meta(meta)
 
 
+def test_snapshot_meta_refuses_old_pool_layout():
+    """A snapshot taken when the pool was ``(L, N, Hkv, bt, hd)`` carries
+    the fingerprint without the ``layout`` field; its pool has as many
+    codes, so only the fingerprint can refuse it."""
+    mgr = _mgr(n_blocks=8, max_slots=3, bt=4)
+    _admit(mgr, 0, list(range(9)))
+    meta = mgr.snapshot_meta()
+    g = mgr.geom
+    meta["geometry"] = (f"paged(bt={g.block_tokens},L={g.n_layers},"
+                        f"kv={g.n_kv}x{g.head_dim},code_B={g.code_bytes},"
+                        f"page_B={g.page_bytes})")
+    fresh = _mgr(n_blocks=8, max_slots=3, bt=4)
+    with pytest.raises(ValueError, match="geometry"):
+        fresh.restore_meta(meta)
+    assert fresh.stats() == _mgr(n_blocks=8, max_slots=3, bt=4).stats()
+
+
 def test_begin_slot_requires_released_table():
     mgr = _mgr()
     _admit(mgr, 0, [1, 2])
@@ -387,3 +404,34 @@ def test_snapshot_restore_mid_stream_zero_loss(paged_setup):
     bare["meta"] = {k: v for k, v in mid["meta"].items() if k != "paged"}
     with pytest.raises(ValueError, match="paged"):
         eng.restore(bare, now=0.0)
+
+
+def test_snapshot_restore_refuses_old_pool_layout(paged_setup):
+    """A snapshot whose pools are in the old ``(L, N, Hkv, bt, hd)`` layout,
+    under the old fingerprint, is refused before anything is installed:
+    reshaped into ``(L, N, bt, Hkv*hd)`` its codes would restore without
+    error, head-major read as token-major."""
+    cfg, model, params, policy = paged_setup
+    eng = PagedContinuousBatchingEngine(model, params, policy, max_slots=2,
+                                        S_max=64, page_bytes=2048,
+                                        n_blocks=16)
+    bt = eng.geom.block_tokens
+    for i, p in enumerate(_prompts(cfg, 2, bt + 2, 0.5)):
+        eng.submit(Request(rid=i, prompt=p, max_new_tokens=4))
+    eng.admit(now=0.0)
+    eng.step(now=0.0)
+    snap = eng.snapshot()
+    kv = snap["arrays"]["cache"]["kv"]
+    g = eng.geom
+    for name in ("k", "v"):
+        L, N, _, _ = kv[name].shape
+        kv[name] = kv[name].reshape(L, N, bt, g.n_kv, g.head_dim).transpose(
+            0, 1, 3, 2, 4)
+    snap["meta"]["paged"]["geometry"] = (
+        f"paged(bt={bt},L={g.n_layers},kv={g.n_kv}x{g.head_dim},"
+        f"code_B={g.code_bytes},page_B={g.page_bytes})")
+    lens, steps = eng.lens.copy(), eng.steps
+    with pytest.raises(ValueError, match="geometry"):
+        eng.restore(snap, now=0.0)
+    assert eng.steps == steps and (eng.lens == lens).all()
+    eng.manager.check_invariants()

@@ -21,6 +21,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_arch
 from repro.core.pcsr import TransPolicy
 from repro.kernels.posit_attention import ops as attn_ops
+from repro.kernels.posit_attention.paged_attention import (
+    posit_decode_attention_paged_pallas,
+)
 from repro.kernels.posit_attention.posit_attention import posit_decode_attention
 from repro.kernels.posit_codec.posit_codec import decode_kernel, encode_kernel
 from repro.models.layers import quantize_params
@@ -71,6 +74,29 @@ def test_decode_attention_compiles(one_chip, kv_bits, code):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("kv_bits,code", [(8, jnp.uint8), (16, jnp.uint16),
+                                          (0, jnp.bfloat16)])
+@pytest.mark.parametrize("heads,kv_heads,head_dim",
+                         [(32, 32, 96), (16, 16, 128)], ids=["phi3", "olmoe"])
+def test_paged_decode_attention_compiles(one_chip, heads, kv_heads, head_dim,
+                                         kv_bits, code):
+    """The paged kernel alone at phi3's and OLMoE's decode widths over the
+    benchmark's pool: 32 slots, pages of 16 tokens, 64 a slot, 1,280."""
+    slots, bt, width, blocks = 32, 16, 64, 1280
+    q = jax.ShapeDtypeStruct((slots, heads, head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+    pool = jax.ShapeDtypeStruct((blocks, bt, kv_heads * head_dim), code,
+                                sharding=one_chip)
+    table = jax.ShapeDtypeStruct((slots, width), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    es = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = _compile(
+        lambda q, k, v, t, n, e: posit_decode_attention_paged_pallas(
+            q, k, v, t, n, e, kv_bits=kv_bits),
+        q, pool, pool, table, lens, es)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("nbits", [8, 16])
 @pytest.mark.parametrize("direction", ["decode", "encode"])
 def test_codec_kernels_compile(one_chip, direction, nbits):
@@ -88,10 +114,10 @@ def test_codec_kernels_compile(one_chip, direction, nbits):
 @pytest.mark.parametrize("engine", ["paged", "slot_grid"])
 def test_decode_step_compiles(one_chip, monkeypatch, engine):
     """One decode step of a 2-layer phi3 at published widths under
-    p8-serve with posit-coded weights, as the serving engines jit it.  The
-    slot grid decodes through the Pallas kernel (what ``attn_impl`` picks on
-    a TPU; the test steers that choice, since this process sees the CPU);
-    the paged step's attention is the tiled XLA path and has no kernel."""
+    p8-serve with posit-coded weights, as the serving engines jit it.  Both
+    steps decode through a Pallas kernel, what ``attn_impl`` picks on a TPU
+    (the test steers that choice, since this process sees the CPU): the
+    slot grid's flash-decode kernel, the paged step's paged kernel."""
     monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
     cfg = dataclasses.replace(get_arch("phi3-mini-3.8b"), n_layers=2)
     model = build_model(cfg)
@@ -110,6 +136,34 @@ def test_decode_step_compiles(one_chip, monkeypatch, engine):
     compiled = jax.jit(lambda p, t, c: step(p, t, c, policy),
                        donate_argnums=(2,)).lower(
         *_on(one_chip, (params, tok, cache))).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == (engine == "slot_grid")
+    assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_paged_step_temporaries_shrink(one_chip, monkeypatch):
+    """phi3's whole paged decode step at the benchmark's geometry (32 layers,
+    32 slots, 64 pages of 16 tokens a slot, 1,280 pages) needs fewer
+    temporary bytes with the paged kernel than with the one-gather lowering
+    (``attn_impl="xla"``, the path every TPU step took before the kernel):
+    the de-paged ``(B, Hkv, W*bt, d)`` code views are gone."""
+    monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
+    model = build_model(get_arch("phi3-mini-3.8b"))
+    slots, bt, width, blocks = 32, 16, 64, 1280
+    temp = {}
+    for impl in ("auto", "xla"):
+        policy = dataclasses.replace(TransPolicy.from_spec("p8-serve"),
+                                     attn_impl=impl)
+        params = jax.eval_shape(
+            lambda k: quantize_params(model.init(k), policy),
+            jax.random.key(0))
+        cache = jax.eval_shape(lambda: model.init_paged_cache(
+            slots, blocks, bt, width, policy))
+        tok = jax.ShapeDtypeStruct((slots,), jnp.int32)
+        compiled = jax.jit(
+            lambda p, t, c: model.decode_step_paged(p, t, c, policy),
+            donate_argnums=(2,)).lower(
+            *_on(one_chip, (params, tok, cache))).compile()
+        assert ("tpu_custom_call" in compiled.as_text()) == (impl == "auto")
+        temp[impl] = compiled.memory_analysis().temp_size_in_bytes
+    assert temp["auto"] < temp["xla"], temp
