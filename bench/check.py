@@ -2,8 +2,8 @@
 
 After the window a sample of the requests finished in it is drawn from
 the seed, the longest one always in it, until it holds ``SAMPLE_TOKENS``
-served tokens.  The configuration's plain float32 reference
-(``reference/<family>.py``) runs once over each prompt with its served
+served tokens.  The plain float32 reference (``reference/common.py``, with
+the configuration's architecture module) runs once over each prompt with its served
 tokens, and the number compared is the widest gap by which a served
 token's reference logit lies below the reference's best logit at that
 position (greedy serving would have picked the best, up to rounding).
@@ -15,13 +15,14 @@ the precision below the configuration's, see ``reference/common.py``).
 """
 from __future__ import annotations
 
-import importlib
 import json
 import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from reference import common
 
 HERE = pathlib.Path(__file__).resolve().parent
 SAMPLE_TOKENS = 384
@@ -46,17 +47,12 @@ def sample(reqs, seed: int) -> list:
     return out
 
 
-def reference(cfg: dict):
-    return importlib.import_module(f"reference.{cfg['reference']}")
-
-
-def served_gaps(m: dict, cfg: dict, seed: int, reqs: list, length: int,
+def served_gaps(m: dict, seed: int, reqs: list, length: int,
                 quant=None) -> tuple:
     """(gaps of the served tokens under the reference, gaps of the tokens
     the reference at ``quant`` puts first); the second is None without
     ``quant``.  One gap per served token, in logits.  ``length`` is the
     mix's S_max: every run of a cell hands the reference the same shapes."""
-    ref = reference(cfg)
     seqs = [r.prompt + r.tokens for r in reqs]
     pos = [list(range(len(r.prompt) - 1, len(r.prompt) - 1 + len(r.tokens)))
            for r in reqs]
@@ -65,12 +61,12 @@ def served_gaps(m: dict, cfg: dict, seed: int, reqs: list, length: int,
     # the sample holds fewer than SAMPLE_TOKENS + one request's tokens, so
     # every run of a cell hands the reference the same shapes
     kw = {"rows": SAMPLE_MAX, "length": length, "n_pad": SAMPLE_TOKENS + length}
-    logits = ref.logits_at(m, seed, seqs, pos, **kw)
+    logits = common.logits_at(m, seed, seqs, pos, **kw)
     served = np.pad(served, (0, kw["n_pad"] - n))
     gaps = np.asarray(_gap(logits, served))[:n]
     ctrl = None
     if quant is not None:
-        low = ref.logits_at(m, seed, seqs, pos, quant=quant, **kw)
+        low = common.logits_at(m, seed, seqs, pos, quant=quant, **kw)
         ctrl = np.asarray(_gap(logits, _argmax(low)))[:n]
     return gaps, ctrl
 

@@ -48,7 +48,7 @@ def one_seed(wl, cfg, m, mix, seed: int, seconds: float) -> dict:
     failed = sum(not (r.ok or r.cut_short) for r in win.attempted)
     if not picked:
         return {"seed": seed, "requests": 0, "failed": failed}
-    gaps, ctrl = check.served_gaps(m, cfg, seed, picked, mix["s_max"],
+    gaps, ctrl = check.served_gaps(m, seed, picked, mix["s_max"],
                                    quant="int4")
     return {"seed": seed, "requests": len(picked), "tokens": int(len(gaps)),
             "failed": failed,

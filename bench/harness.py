@@ -26,9 +26,9 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+import reference
 import traffic
 import weights
-import work
 
 now = time.perf_counter
 
@@ -36,31 +36,35 @@ now = time.perf_counter
 # ------------------------------------------------------------------ model ---
 
 def model_cfg(m: dict, cfg: dict):
-    """The program's ``ModelCfg`` for a configuration file's sizes."""
+    """The program's ``ModelCfg`` for a configuration file's sizes: the
+    fields every configuration has, and its architecture module's
+    (``reference/<name>.py``)."""
     from repro.configs.base import ModelCfg
     return ModelCfg(
         name=cfg["name"], family=cfg["family"],
         n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"], n_kv=m["num_key_value_heads"],
-        d_ff=m["intermediate_size"], vocab=m["vocab_size"],
-        rope_base=float(m["rope_theta"]), norm_eps=float(m["rms_norm_eps"]),
+        vocab=m["vocab_size"], norm_eps=float(m["rms_norm_eps"]),
         tie_embeddings=bool(m.get("tie_word_embeddings", False)),
-        n_experts=m.get("num_experts", 0),
-        top_k=m.get("num_experts_per_tok", 0),
-        capacity_factor=float(cfg["program"]["capacity_factor"]))
+        capacity_factor=float(cfg["program"]["capacity_factor"]),
+        **reference.load(m).model_fields(m))
+
+
+# Fields the configuration file sets itself: its name, the published RMSNorm
+# epsilon (the registered architectures keep the program's default), and the
+# expert capacity it chooses (``program.capacity_factor``).
+OWN_FIELDS = ("name", "norm_eps", "capacity_factor")
 
 
 def check_registered(cfg: dict, mc) -> None:
     """At the published size the sizes must be the program's registered
     architecture's: a configuration never runs a model the program does
-    not list."""
+    not list.  Every ``ModelCfg`` field is compared but ``OWN_FIELDS``."""
     from repro.configs import get_arch
-    arch = get_arch(cfg["program"]["arch"])
-    for f in ("n_layers", "d_model", "n_heads", "n_kv", "d_ff", "vocab",
-              "n_experts", "top_k", "family"):
-        if getattr(arch, f) != getattr(mc, f):
-            raise ValueError(f"{cfg['name']}: {f} {getattr(mc, f)} differs "
-                             f"from {arch.name}'s {getattr(arch, f)}")
+    arch = dataclasses.asdict(get_arch(cfg["program"]["arch"]))
+    for f, v in dataclasses.asdict(mc).items():
+        if f not in OWN_FIELDS and arch[f] != v:
+            raise ValueError(f"{cfg['name']}: {f} {v} differs from "
+                             f"{arch['name']}'s {arch[f]}")
 
 
 @dataclasses.dataclass
@@ -77,8 +81,7 @@ def serve_config(cell: Cell):
     s, mix = cell.cfg["serve"], cell.mix
     gen = mix["output"]["max"]
     page_bytes = cell.m.get("page_bytes", s["page_bytes"])
-    block_tokens = page_bytes // (2 * cell.m["num_key_value_heads"]
-                                  * work.head_dim(cell.m))
+    block_tokens = page_bytes // reference.load(cell.m).page_row_bytes(cell.m)
     return ServeConfig(
         arch=cell.cfg["program"]["arch"], continuous=True, paged=True,
         policy=s["policy"], quantize_weights=s["quantize_weights"],

@@ -1,12 +1,15 @@
-"""Plain float32 decoder-only transformer, the part every family shares.
+"""Plain float32 decoder-only transformer, the part every architecture
+shares.
 
 Straightforward ``jax.numpy`` at ``Precision.HIGHEST``: no kernels, no cache,
-no batching tricks.  It follows the published decoder (pre-norm RMSNorm,
-rotary embedding on q and k with the half-split rotation, causal
-multi-head attention scaled by head_dim^-0.5, residual adds, final RMSNorm,
-untied output head), with the departures each configuration file lists.
-Weights come from ``weights.py`` one layer at a time, so the reference fits
-beside nothing else on the device.  It imports nothing of the program.
+no batching tricks.  It follows the published decoder (embedding, pre-norm
+layers, residual adds, final RMSNorm, untied output head), with the
+departures each configuration file lists; the two halves of each layer are
+the configuration's architecture module's (``reference/<name>.py``), which
+builds them from the pieces here (RMSNorm, the half-split rotary embedding,
+causal attention).  Weights are regenerated from the seed one layer at a
+time, so the reference fits beside nothing else on the device.  It imports
+nothing of the program.
 
 ``quant="int4"`` is the control: every linear weight rounded to 4-bit
 integers with one absmax scale per output channel, the precision step below
@@ -20,8 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import reference
 import weights
-import work
 
 HI = jax.lax.Precision.HIGHEST
 Q_CHUNK = 256
@@ -77,35 +80,32 @@ def causal_attention(q, k, v):
     return out.transpose(1, 0, 2, 3, 4).reshape(B, T, Hq, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("mj", "quant"))
-def attn_block(x, lo, hi, layer, mj, quant):
-    """Residual attention half of one layer; returns (x + attn, ln2(x+attn))."""
-    m = dict(mj)
-    p = weights.layer_floats(m, (lo, hi), layer)
-    a = p["attn"]
-    hq, hkv, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                   work.head_dim(m))
-    B, T, _ = x.shape
-    h = rmsnorm(x, p["ln1"]["g"], m["rms_norm_eps"])
-    q = mm(h, fake_quant(a["wq"]["w"], quant)).reshape(B, T, hq, hd)
-    k = mm(h, fake_quant(a["wk"]["w"], quant)).reshape(B, T, hkv, hd)
-    v = mm(h, fake_quant(a["wv"]["w"], quant)).reshape(B, T, hkv, hd)
-    q, k = rope(q, base=m["rope_theta"]), rope(k, base=m["rope_theta"])
-    o = causal_attention(q, k, v).reshape(B, T, hq * hd)
-    x2 = x + mm(o, fake_quant(a["wo"]["w"], quant))
-    return x2, rmsnorm(x2, p["ln2"]["g"], m["rms_norm_eps"])
+weights.declare({"embed": 12, "lm_head": 13})
+
+
+def top_floats(m: dict, words) -> dict:
+    """The embedding, the final norm and the untied output head."""
+    d, V = m["hidden_size"], m["vocab_size"]
+    return {
+        "embed": {"table": weights.normal(words, weights.TOP, "embed",
+                                          (V, d), 1.0)},
+        "final_norm": {"g": weights.gain(d)},
+        "lm_head": {"w": weights.normal(words, weights.TOP, "lm_head",
+                                        (d, V))},
+    }
 
 
 @functools.partial(jax.jit, static_argnames=("mj",))
 def embed(tokens, lo, hi, mj):
-    return weights.top_floats(dict(mj), (lo, hi))["embed"]["table"][tokens]
+    m = dict(mj)
+    return reference.load(m).top_floats(m, (lo, hi))["embed"]["table"][tokens]
 
 
 @functools.partial(jax.jit, static_argnames=("mj", "quant"))
 def head(x, rows, cols, lo, hi, mj, quant):
     """Logits at (rows, cols) of x: (N, V)."""
     m = dict(mj)
-    t = weights.top_floats(m, (lo, hi))
+    t = reference.load(m).top_floats(m, (lo, hi))
     h = rmsnorm(x[rows, cols], t["final_norm"]["g"], m["rms_norm_eps"])
     return mm(h, fake_quant(t["lm_head"]["w"], quant))
 
@@ -116,19 +116,21 @@ def frozen(m: dict):
                         if isinstance(v, (int, float, bool, str))))
 
 
-def logits_at(m: dict, seed: int, seqs, positions, mlp, *, quant=None,
+def logits_at(m: dict, seed: int, seqs, positions, *, quant=None,
               rows: int = 8, length: int = 0, n_pad: int = 0):
     """Logits of the model at given positions of given sequences.
 
     ``seqs``: token-id lists; ``positions[i]``: positions of ``seqs[i]``
-    whose next-token logits are wanted.  ``mlp(h, lo, hi, layer, mj,
-    quant)`` is the family's second half of a layer.  The batch is padded
-    to ``rows`` sequences of at least ``length`` tokens (a multiple of
-    Q_CHUNK) and the positions to at least ``n_pad``, so that a cell's every
-    run has the same shapes and compiles once.  Returns an (N, vocab)
-    float32 device array, rows in the order of ``positions`` (N the number
-    of positions, or ``n_pad``; padded rows repeat the last position).
+    whose next-token logits are wanted.  The layers run stack by stack,
+    each through its architecture module's ``attn`` and ``mlp``.  The batch
+    is padded to ``rows`` sequences of at least ``length`` tokens (a
+    multiple of Q_CHUNK) and the positions to at least ``n_pad``, so that a
+    cell's every run has the same shapes and compiles once.  Returns an
+    (N, vocab) float32 device array, rows in the order of ``positions`` (N
+    the number of positions, or ``n_pad``; padded rows repeat the last
+    position).
     """
+    arch = reference.load(m)
     mj = frozen(m)
     lo, hi = weights.seed_words(seed)
     T = max([length] + [len(s) for s in seqs])
@@ -137,10 +139,11 @@ def logits_at(m: dict, seed: int, seqs, positions, mlp, *, quant=None,
     for i, s in enumerate(seqs):
         tokens[i, :len(s)] = s
     x = embed(jnp.asarray(tokens), lo, hi, mj)
-    for layer in range(m["num_hidden_layers"]):
-        li = jnp.int32(layer)
-        x2, h = attn_block(x, lo, hi, li, mj, quant)
-        x = x2 + mlp(h, lo, hi, li, mj, quant)
+    for stack, layers in arch.stacks(m).items():
+        for layer in layers:
+            li = jnp.int32(layer)
+            x2, h = arch.attn(x, lo, hi, stack, li, mj, quant)
+            x = x2 + arch.mlp(h, lo, hi, stack, li, mj, quant)
     rows = np.concatenate([np.full(len(p), i, np.int32)
                            for i, p in enumerate(positions)])
     cols = np.concatenate([np.asarray(p, np.int32) for p in positions])

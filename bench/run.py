@@ -175,7 +175,7 @@ def main(argv=None, *, platform: str = "tpu") -> int:
     import check
     t = time.perf_counter()
     picked = check.sample(win.finished(), args.seed)
-    gaps = check.served_gaps(m, cfg, args.seed, picked,
+    gaps = check.served_gaps(m, args.seed, picked,
                              mix["s_max"])[0] if picked else []
     readings = check.numbers(gaps)
     ok, compared = check.compare(
